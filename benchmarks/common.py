@@ -39,13 +39,13 @@ def write_csv(name: str, header: list[str], rows: list[list]) -> str:
     return path
 
 
-def run_with_devices(module: str, args: list[str], devices: int,
-                     timeout: int = 900) -> str:
-    """Run a repro module in a subprocess with a forced device count
-    (the MPI-procs analogue for scaling benchmarks)."""
+def run_module(module: str, args: list[str], timeout: int = 900) -> str:
+    """Run a repro module in a subprocess and return its stdout.  The
+    caller must not have touched JAX: on an accelerator the child needs
+    the devices.  ``repro.launch.sssp_run --procs P`` sets up its own P
+    devices (emulated host devices on the CPU backend)."""
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     r = subprocess.run([sys.executable, "-m", module, *args],
                        capture_output=True, text=True, env=env,
                        timeout=timeout)

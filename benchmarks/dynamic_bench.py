@@ -207,4 +207,6 @@ if __name__ == "__main__":
                     help="write per-round repair/full cost records as "
                          "JSONL (repro/obs/profile.py schema)")
     args = ap.parse_args()
+    from repro.launch.runtime import enable_compile_cache
+    enable_compile_cache()
     run(args.smoke, out=args.out, cost_out=args.cost_out)

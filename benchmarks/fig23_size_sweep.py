@@ -42,4 +42,7 @@ def run(quick: bool = False):
 
 if __name__ == "__main__":
     import sys
+
+    from repro.launch.runtime import enable_compile_cache
+    enable_compile_cache()
     run("--quick" in sys.argv)

@@ -33,36 +33,14 @@ exact, so agreement is exact equality, not allclose).
 ``--smoke`` caps every corpus for CI (< ~1 min on CPU); ``--full`` extends
 the sparse corpus to the paper's 40,000-vertex ceiling point.  ``--devices
 P`` (default 4) adds the vertex-partitioned sharded CSR engines on a
-P-device mesh — on CPU the device count is forced before jax initializes,
-the MPI-procs analogue; ``--devices 1`` drops the sharded leg.
+P-device mesh — emulated host devices on CPU, the MPI-procs analogue;
+``--devices 1`` drops the sharded leg.
 """
 from __future__ import annotations
 
-import os
-import sys
-
-# Device count must be fixed before jax initializes; parse --devices by
-# hand (same pattern as launch/sssp_run.py's --procs).
-_DEFAULT_DEVICES = 4
-if __name__ == "__main__" and "--help" not in sys.argv and "-h" not in sys.argv:
-    _n = _DEFAULT_DEVICES
-    for _i, _a in enumerate(sys.argv):
-        # accept both `--devices N` and `--devices=N`; malformed values
-        # fall through to argparse below for the proper usage error.
-        try:
-            if _a == "--devices":
-                _n = int(sys.argv[_i + 1])
-            elif _a.startswith("--devices="):
-                _n = int(_a.split("=", 1)[1])
-        except (IndexError, ValueError):
-            break
-    if _n > 1:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={_n} "
-            + os.environ.get("XLA_FLAGS", ""))
-
 import argparse
 import json
+import os
 import platform
 import time
 
@@ -74,6 +52,7 @@ from benchmarks.common import REPO, time_engine
 from repro.core import csr as C
 from repro.core import graph as G
 from repro.core.api import shortest_paths
+from repro.launch.runtime import enable_compile_cache, use_devices
 
 DEFAULT_OUT = os.path.join(REPO, "BENCH_sssp.json")
 
@@ -333,13 +312,8 @@ def run(smoke: bool = False, full: bool = False, repeats: int = 3,
     sparse_cap = 1000 if smoke else (40000 if full else 20000)
     mesh = None
     if devices > 1:
-        if jax.device_count() < devices:
-            raise SystemExit(
-                f"--devices {devices} needs {devices} XLA devices but only "
-                f"{jax.device_count()} exist (run via `python -m "
-                f"benchmarks.run_bench`, which forces the host device count)")
-        from repro.core._compat import make_mesh
-        mesh = make_mesh((devices,), ("data",))
+        from repro.core._axes import make_mesh
+        mesh = make_mesh((devices,), ("data",), devices=use_devices(devices))
     sparse_engines = SPARSE_ENGINES + (SHARDED_CSR if mesh is not None else ())
     results = []
     for n, m in G.PAPER_DENSE:
@@ -402,12 +376,14 @@ if __name__ == "__main__":
                     help="extend sparse corpus to the paper's n=40000")
     ap.add_argument("--repeats", type=int, default=3)
     ap.add_argument("--out", default=DEFAULT_OUT)
-    ap.add_argument("--devices", type=int, default=_DEFAULT_DEVICES,
-                    help="mesh size for the sharded CSR engines (forced "
-                         "host device count on CPU); 1 drops the leg")
+    ap.add_argument("--devices", type=int, default=4,
+                    help="mesh size for the sharded CSR engines (emulated "
+                         "host devices on CPU); 1 drops the leg")
     ap.add_argument("--cost-out", default=None, metavar="PATH",
                     help="write one per-solve cost record per engine call "
                          "as JSONL (repro/obs/profile.py schema)")
     args = ap.parse_args()
+    enable_compile_cache()
+    use_devices(args.devices)
     run(args.smoke, args.full, repeats=args.repeats, out=args.out,
         devices=args.devices, cost_out=args.cost_out)

@@ -20,7 +20,7 @@ verified points is checked bitwise against a fresh ``serial`` solve.
 
 ``--devices P`` (default 1) adds the SHARDED serving leg: the same Zipf
 replay on a larger graph routed through the vertex-partitioned engines
-(serve/dispatch.py) on a P-device mesh — forced host devices on CPU, the
+(serve/dispatch.py) on a P-device mesh — emulated host devices on CPU, the
 MPI-procs analogue — against the single-device serve stack on the same
 graph.  Its ``gate_sharded`` asserts the union-frontier engine relaxes
 STRICTLY fewer edges per solved source than per-query single-device
@@ -52,29 +52,10 @@ Spliced into EXPERIMENTS.md by benchmarks/make_experiments_md.py.
 """
 from __future__ import annotations
 
-import os
-import sys
-
-# Device count must be fixed before jax initializes; parse --devices by
-# hand (same pattern as run_bench.py).
-if __name__ == "__main__" and "--help" not in sys.argv and "-h" not in sys.argv:
-    _n = 1
-    for _i, _a in enumerate(sys.argv):
-        try:
-            if _a == "--devices":
-                _n = int(sys.argv[_i + 1])
-            elif _a.startswith("--devices="):
-                _n = int(_a.split("=", 1)[1])
-        except (IndexError, ValueError):
-            break
-    if _n > 1:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={_n} "
-            + os.environ.get("XLA_FLAGS", ""))
-
 import argparse
 import dataclasses
 import json
+import os
 import platform
 import time
 
@@ -85,6 +66,7 @@ import jax
 from benchmarks.common import REPO
 from repro.core import csr as C
 from repro.core.api import shortest_paths
+from repro.launch.runtime import enable_compile_cache, use_devices
 from repro.serve import (DispatchPolicy, DistanceCache, GraphRegistry,
                          MicroBatchScheduler, QueryRejected, SCENARIOS,
                          make_trace)
@@ -125,7 +107,7 @@ def _make_scheduler(cg, dispatch=None, **sched_kwargs):
 
         ch = dispatch.choose(handle, kind="batch")
         parts = handle.partition(ch.nprocs)
-        pops = handle.partition_ops(ch.nprocs)
+        pops = handle.partition_ops(ch.mesh, ch.axis)
         b = 1
         while True:
             sssp_multisource_csr_sharded(
@@ -584,8 +566,8 @@ if __name__ == "__main__":
                     help="CI-sized corpus (n=1000, short traces)")
     ap.add_argument("--out", default=DEFAULT_OUT)
     ap.add_argument("--devices", type=int, default=1,
-                    help="mesh size for the sharded leg (host devices are "
-                         "forced before jax init; 1 = skip the leg)")
+                    help="mesh size for the sharded leg (emulated host "
+                         "devices on CPU; 1 = skip the leg)")
     ap.add_argument("--overload", action="store_true",
                     help="add the 2x-offered-load degraded-mode leg and "
                          "its shed-don't-collapse gate")
@@ -597,5 +579,7 @@ if __name__ == "__main__":
                     help="with --obs: write + validate the enabled leg's "
                          "Chrome trace (and .cost.jsonl) here")
     args = ap.parse_args()
+    enable_compile_cache()
+    use_devices(args.devices)
     run(args.smoke, out=args.out, devices=args.devices,
         overload=args.overload, obs=args.obs, trace_out=args.trace_out)

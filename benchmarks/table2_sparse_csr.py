@@ -62,4 +62,6 @@ if __name__ == "__main__":
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--dense-cap", type=int, default=10000)
     args = ap.parse_args()
+    from repro.launch.runtime import enable_compile_cache
+    enable_compile_cache()
     run(args.quick, dense_cap=args.dense_cap)

@@ -3,8 +3,9 @@
 The paper's claim: with an adjacency matrix, processing time depends on n,
 not edge count.  We time the three implementations (serial = Alg.1;
 bellman = the CUDA analogue's algorithm; dijkstra_sharded = the MPI
-analogue, run across forced host devices in a subprocess) on the paper's
-graph corpus.
+analogue, on an 8-device mesh in the same process) on the paper's graph
+corpus.  The mesh is 8 emulated host devices on CPU; on an accelerator
+host with fewer than 8 devices the run stops instead of sharing them.
 
 CPU caveat recorded in EXPERIMENTS.md: absolute times are CPU times of the
 TPU-targeted program (the kernel path runs in interpret mode); the
@@ -12,15 +13,13 @@ TPU-targeted program (the kernel path runs in interpret mode); the
 """
 from __future__ import annotations
 
-import re
-
-import numpy as np
-
-import jax.numpy as jnp
-
-from benchmarks.common import run_with_devices, time_engine, write_csv
+from benchmarks.common import time_engine, write_csv
 from repro.core import graph as G
+from repro.core._axes import make_mesh
 from repro.core.api import shortest_paths
+from repro.launch.runtime import enable_compile_cache, use_devices
+
+PROCS = 8
 
 PAIRS = [
     (10, 30), (10, 45),
@@ -32,19 +31,17 @@ PAIRS = [
 
 def run(quick: bool = False):
     pairs = PAIRS[:6] if quick else PAIRS
+    mesh = make_mesh((PROCS,), ("data",), devices=use_devices(PROCS))
     rows = []
     for n, m in pairs:
         g = G.random_graph(n, m, seed=n + m)
-        adj = jnp.asarray(g.adj)
         t_serial = time_engine(
             lambda: shortest_paths(g, 0, engine="serial"))
         t_bell = time_engine(
             lambda: shortest_paths(g, 0, engine="bellman"))
-        out = run_with_devices(
-            "repro.launch.sssp_run",
-            ["--engine", "dijkstra_sharded", "--procs", "8",
-             "--nodes", str(n), "--edges", str(m), "--repeats", "2"], 8)
-        t_mpi = float(re.search(r"time=([\d.e+-]+)s", out).group(1))
+        t_mpi = time_engine(
+            lambda: shortest_paths(g, 0, engine="dijkstra_sharded",
+                                   mesh=mesh), repeats=2)
         rows.append([n, m, f"{t_serial:.6f}", f"{t_mpi:.6f}",
                      f"{t_bell:.6f}"])
         print(f"n={n:6d} m={m:8d} serial={t_serial:.6f}s "
@@ -67,4 +64,5 @@ def run(quick: bool = False):
 
 if __name__ == "__main__":
     import sys
+    enable_compile_cache()
     run("--quick" in sys.argv)

@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import re
 
-from benchmarks.common import run_with_devices, write_csv
+from benchmarks.common import run_module, write_csv
 
 PROCS = (1, 2, 4, 8, 16)
 
@@ -28,11 +28,10 @@ def run(quick: bool = False, n: int = 2048):
     base = {}
     for engine in ("dijkstra_sharded", "bellman_sharded"):
         for procs in PROCS if not quick else PROCS[:4]:
-            out = run_with_devices(
+            out = run_module(
                 "repro.launch.sssp_run",
                 ["--engine", engine, "--procs", str(procs),
-                 "--nodes", str(n), "--edges", str(m), "--repeats", "2"],
-                procs)
+                 "--nodes", str(n), "--edges", str(m), "--repeats", "2"])
             t = _time_of(out)
             if procs == 1:
                 base[engine] = t

@@ -26,29 +26,9 @@ at least one leg), not the >=5%-win economics.
 """
 from __future__ import annotations
 
-import os
-import sys
-
-# Device count must be fixed before jax initializes; parse --devices by
-# hand (same pattern as run_bench.py).
-_DEFAULT_DEVICES = 4
-if __name__ == "__main__" and "--help" not in sys.argv and "-h" not in sys.argv:
-    _n = _DEFAULT_DEVICES
-    for _i, _a in enumerate(sys.argv):
-        try:
-            if _a == "--devices":
-                _n = int(sys.argv[_i + 1])
-            elif _a.startswith("--devices="):
-                _n = int(_a.split("=", 1)[1])
-        except (IndexError, ValueError):
-            break
-    if _n > 1:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={_n} "
-            + os.environ.get("XLA_FLAGS", ""))
-
 import argparse
 import json
+import os
 import platform
 import time
 from typing import Any, Dict, List, Optional
@@ -56,6 +36,7 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from benchmarks.common import time_engine
+from repro.launch.runtime import enable_compile_cache, use_devices
 
 DEFAULT_OUT = "BENCH_tune.json"
 DEFAULT_CALIBRATION = "CALIBRATION.json"
@@ -213,7 +194,7 @@ def _gate_tune(rows: List[Dict[str, Any]], *, smoke: bool,
 
 
 def run(smoke: bool = False, repeats: int = 3,
-        devices: int = _DEFAULT_DEVICES,
+        devices: int = 4,
         calibration: str = DEFAULT_CALIBRATION,
         out: str = DEFAULT_OUT,
         cost_out: Optional[str] = None) -> str:
@@ -230,11 +211,8 @@ def run(smoke: bool = False, repeats: int = 3,
     model = load_model(calibration)
     legs = SMOKE_LEGS if smoke else FULL_LEGS
     proc_list = [1] + ([devices] if devices > 1 else [])
-    if devices > 1 and jax.device_count() < devices:
-        raise SystemExit(
-            f"--devices {devices} needs {devices} XLA devices but only "
-            f"{jax.device_count()} exist (run via `python -m "
-            f"benchmarks.tune_bench`, which forces the host count)")
+    if devices > 1:
+        use_devices(devices)
 
     cost_log = CostLog() if cost_out else None
     prev = set_cost_log(cost_log) if cost_log is not None else None
@@ -302,9 +280,9 @@ if __name__ == "__main__":
                     help="CI-sized corpora below the calibrated "
                          "crossovers (parity + engagement gate only)")
     ap.add_argument("--repeats", type=int, default=3)
-    ap.add_argument("--devices", type=int, default=_DEFAULT_DEVICES,
-                    help="mesh size for the P>1 legs (forced host device "
-                         "count on CPU); 1 drops them")
+    ap.add_argument("--devices", type=int, default=4,
+                    help="mesh size for the P>1 legs (emulated host "
+                         "devices on CPU); 1 drops them")
     ap.add_argument("--calibration", default=DEFAULT_CALIBRATION,
                     help="CALIBRATION.json to fit the model from")
     ap.add_argument("--out", default=DEFAULT_OUT)
@@ -312,6 +290,8 @@ if __name__ == "__main__":
                     help="write the race's cost records as JSONL (feeds "
                          "the repro.tune.replay gate)")
     args = ap.parse_args()
+    enable_compile_cache()
+    use_devices(args.devices)
     run(args.smoke, repeats=args.repeats, devices=args.devices,
         calibration=args.calibration, out=args.out,
         cost_out=args.cost_out)

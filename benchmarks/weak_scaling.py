@@ -20,7 +20,7 @@ from __future__ import annotations
 
 import re
 
-from benchmarks.common import run_with_devices, write_csv
+from benchmarks.common import run_module, write_csv
 
 PROCS = (1, 2, 4, 8)
 ENGINES = ("dijkstra_sharded", "bellman_sharded",
@@ -38,11 +38,11 @@ def run(quick: bool = False, base_n: int = 512):
         t1 = None
         for procs in PROCS:
             n = eng_base * procs
-            out = run_with_devices(
+            out = run_module(
                 "repro.launch.sssp_run",
                 ["--engine", engine, "--procs", str(procs),
                  "--nodes", str(n), "--edges", str(3 * n),
-                 "--repeats", "2"], procs)
+                 "--repeats", "2"])
             t = float(re.search(r"time=([\d.e+-]+)s", out).group(1))
             t1 = t1 or t
             eff = t1 / t * 100            # weak-scaling efficiency

@@ -15,7 +15,7 @@ import numpy as np
 import jax
 
 from repro.core import graph as G
-from repro.core._compat import make_mesh
+from repro.core._axes import make_mesh
 from repro.core.api import (CSR_ENGINES, DELTA_ENGINES, ENGINES,
                             SHARDED_CSR_ENGINES, shortest_paths)
 from repro.core.serial import dijkstra_serial_np

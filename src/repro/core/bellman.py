@@ -35,11 +35,10 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.core._axes import axis_size, axis_tuple
-from repro.core._compat import pvary, shard_map
+from repro.core._axes import axis_size, varying
 
 INF = jnp.inf
 
@@ -172,8 +171,8 @@ def sssp_bellman_sharded(
         v_base = my_p * loc_n
         dist0 = jnp.full((n_pad,), INF, adj_loc.dtype).at[src].set(0.0)
         # initial carries are device-invariant; body outputs are varying.
-        dist0 = pvary(dist0, axis_tuple(axis))
-        prev0 = pvary(jnp.full((n_pad,), -1.0, adj_loc.dtype), axis_tuple(axis))
+        dist0 = varying(dist0, axis)
+        prev0 = varying(jnp.full((n_pad,), -1.0, adj_loc.dtype), axis)
 
         def cond(c):
             dist, prev, it = c
@@ -187,7 +186,7 @@ def sssp_bellman_sharded(
             new = lax.all_gather(loc_new, axis, tiled=True)      # (n_pad,)
             return new, dist, it + 1
 
-        it0 = pvary(jnp.int32(0), axis_tuple(axis))
+        it0 = varying(jnp.int32(0), axis)
         dist, _, sweeps = lax.while_loop(cond, body, (dist0, prev0, it0))
         # local pred for owned vertices, from the fixpoint dist.  Mask the
         # diagonal (global row v for local column v) so the argmin never

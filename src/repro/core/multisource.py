@@ -18,11 +18,10 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.core._axes import axis_size, axis_tuple
-from repro.core._compat import pvary, shard_map
+from repro.core._axes import axis_size, varying
 
 INF = jnp.inf
 
@@ -94,8 +93,8 @@ def sssp_multisource_sharded(
     def run(adj_loc, srcs):
         my_p = lax.axis_index(axis)
         v_base = my_p * loc_n
-        D0 = pvary(init_dist(n_pad, srcs, adj_loc.dtype), axis_tuple(axis))
-        prev0 = pvary(jnp.full((s, n_pad), -1.0, adj_loc.dtype), axis_tuple(axis))
+        D0 = varying(init_dist(n_pad, srcs, adj_loc.dtype), axis)
+        prev0 = varying(jnp.full((s, n_pad), -1.0, adj_loc.dtype), axis)
 
         def cond(c):
             D, prev, it = c
@@ -110,7 +109,7 @@ def sssp_multisource_sharded(
             new = lax.all_gather(loc_new, axis, axis=1, tiled=True)
             return new, D, it + 1
 
-        it0 = pvary(jnp.int32(0), axis_tuple(axis))
+        it0 = varying(jnp.int32(0), axis)
         D, _, sweeps = lax.while_loop(cond, body, (D0, prev0, it0))
         mine = lax.dynamic_slice_in_dim(D, v_base, loc_n, axis=1)
         return mine, lax.psum(sweeps, axis) // nprocs

@@ -7,8 +7,7 @@ per iteration does: local argmin over the unvisited owned vertices, a global
 the winning vertex's row; results are reassembled with ``MPI_Gather``.
 
 TPU/JAX mapping (see DESIGN.md §2):
-  * processes            -> mesh devices along one axis, via the
-                            version-portable shard_map (core/_compat.py)
+  * processes            -> mesh devices along one axis, via jax.shard_map
   * column partition     -> in_specs P(None, axis) on the padded adjacency
   * MPI_Allreduce MINLOC -> minloc_allgather (baseline: one lax.all_gather of
                             P (dist, index) candidates + deterministic argmin)
@@ -22,11 +21,10 @@ from typing import Literal
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.core._axes import axis_size, axis_tuple
-from repro.core._compat import pvary, shard_map
+from repro.core._axes import axis_size, varying
 
 INF = jnp.inf
 
@@ -121,10 +119,10 @@ def dijkstra_sharded(
         owned = v_base + jnp.arange(loc_n, dtype=jnp.int32)
 
         loc_dist = jnp.where(owned == src, 0.0, INF).astype(adj_loc.dtype)
-        # pvary: mark the device-invariant initial carries as axis-varying so
+        # varying: mark the device-invariant initial carries as axis-varying so
         # the fori_loop carry types match the (varying) body outputs.
-        loc_pred = pvary(jnp.full((loc_n,), -1, jnp.int32), axis_tuple(axis))
-        loc_visited = pvary(jnp.zeros((loc_n,), jnp.bool_), axis_tuple(axis))
+        loc_pred = varying(jnp.full((loc_n,), -1, jnp.int32), axis)
+        loc_visited = varying(jnp.zeros((loc_n,), jnp.bool_), axis)
 
         def body(_, carry):
             loc_dist, loc_pred, loc_visited = carry

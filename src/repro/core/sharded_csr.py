@@ -51,33 +51,32 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from jax.sharding import PartitionSpec as P
+from jax import lax, shard_map
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core._axes import axis_size, axis_tuple
-from repro.core._compat import pvary, shard_map
+from repro.core._axes import axis_size, varying
 from repro.core.frontier import relax_edge_slots, relax_edge_slots_multi
 from repro.obs.metrics import mark_trace
 
 INF = jnp.inf
 
 
-def partition_operands(parts) -> dict:
-    """Stage a core.csr.CsrPartition onto the device as the pytree the
-    sharded engines consume.  Not memoized, same rationale as
-    ``csr_operands``: the host numpy blocks are already cached on the
-    CsrGraph, so repeat staging is a plain copy, and caching jax buffers
-    on the host container would pin device memory.  Long-lived callers
-    that SHOULD pin (serve/registry.py's graph handles) stage once and
-    pass the dict back through the engines' ``ops=``."""
-    return {
-        "in_src": jnp.asarray(parts.in_src),
-        "in_dst_loc": jnp.asarray(parts.in_dst_loc),
-        "in_w": jnp.asarray(parts.in_w),
-        "out_indptr": jnp.asarray(parts.out_indptr),
-        "out_dst_loc": jnp.asarray(parts.out_dst_loc),
-        "out_w": jnp.asarray(parts.out_w),
-    }
+def partition_operands(parts, mesh: jax.sharding.Mesh,
+                       axis: str = "data") -> dict:
+    """Stage a core.csr.CsrPartition onto ``mesh`` as the pytree the
+    sharded engines consume: each owner's row block goes to the device
+    that owns it (``NamedSharding(mesh, P(axis))`` over the leading owner
+    axis), so ``shard_map`` finds every block in place instead of
+    resharding from one device on each call.  Not memoized, same
+    rationale as ``csr_operands``: the host numpy blocks are already
+    cached on the CsrGraph, so repeat staging is a plain copy, and caching
+    jax buffers on the host container would pin device memory.  Long-lived
+    callers that SHOULD pin (serve/registry.py's graph handles) stage once
+    and pass the dict back through the engines' ``ops=``."""
+    owners = NamedSharding(mesh, P(_axis_key(axis), None))
+    return {k: jax.device_put(getattr(parts, k), owners)
+            for k in ("in_src", "in_dst_loc", "in_w", "out_indptr",
+                      "out_dst_loc", "out_w")}
 
 
 def sssp_bellman_csr_sharded(
@@ -108,7 +107,7 @@ def sssp_bellman_csr_sharded(
     assert parts.nprocs == nprocs, (parts.nprocs, nprocs)
     cap = int(parts.n_pad if max_sweeps is None else max_sweeps)
     if ops is None:
-        ops = partition_operands(parts)
+        ops = partition_operands(parts, mesh, axis)
     run = _build_bellman(mesh, _axis_key(axis), parts.n_pad, parts.loc_n,
                          cap)
     return run(ops["in_src"], ops["in_dst_loc"], ops["in_w"],
@@ -140,8 +139,8 @@ def _build_bellman(mesh, axis, n_pad, loc_n, cap):
         my_p = lax.axis_index(axis)
         v_base = (my_p * loc_n).astype(jnp.int32)
         dist0 = jnp.full((n_pad,), INF, in_w.dtype).at[src].set(0.0)
-        dist0 = pvary(dist0, axis_tuple(axis))
-        prev0 = pvary(jnp.full((n_pad,), -1.0, in_w.dtype), axis_tuple(axis))
+        dist0 = varying(dist0, axis)
+        prev0 = varying(jnp.full((n_pad,), -1.0, in_w.dtype), axis)
 
         def seg_min(vals):
             return jax.ops.segment_min(
@@ -160,7 +159,7 @@ def _build_bellman(mesh, axis, n_pad, loc_n, cap):
             new = lax.all_gather(loc_new, axis, tiled=True)
             return new, dist, it + 1
 
-        it0 = pvary(jnp.int32(0), axis_tuple(axis))
+        it0 = varying(jnp.int32(0), axis)
         dist, prev, sweeps = lax.while_loop(cond, body, (dist0, prev0, it0))
         # every device computes the identical flag from the identical
         # gathered vectors; the psum//nprocs makes replication explicit
@@ -223,7 +222,7 @@ def sssp_frontier_sharded(
     assert parts.nprocs == nprocs, (parts.nprocs, nprocs)
     cap = int(parts.n_pad if max_sweeps is None else max_sweeps)
     if ops is None:
-        ops = partition_operands(parts)
+        ops = partition_operands(parts, mesh, axis)
     run = _build_frontier(mesh, _axis_key(axis), parts.n_pad, parts.loc_n,
                           parts.nnz_max, cap,
                           int(min(exchange_chunk, max(parts.loc_n, 1))),
@@ -302,9 +301,9 @@ def _build_frontier(mesh, axis, n_pad, loc_n, nnz_max, cap, CH, RC):
             go = lax.psum(jnp.any(improved).astype(jnp.int32), axis) > 0
             return nd, improved, it + 1, edges, go
 
-        it0 = pvary(jnp.int32(0), axis_tuple(axis))
-        e0 = pvary(jnp.int32(0), axis_tuple(axis))
-        go0 = pvary(jnp.bool_(True), axis_tuple(axis))
+        it0 = varying(jnp.int32(0), axis)
+        e0 = varying(jnp.int32(0), axis)
+        go0 = jnp.bool_(True)       # invariant: the body returns a psum
         dist, _, sweeps, edges, go = lax.while_loop(
             cond, body, (dist0, fmask0, it0, e0, go0))
         # go is the psummed work-remains flag (replicated): exiting with
@@ -362,7 +361,7 @@ def sssp_multisource_csr_sharded(
     assert parts.nprocs == nprocs, (parts.nprocs, nprocs)
     cap = int(parts.n_pad if max_sweeps is None else max_sweeps)
     if ops is None:
-        ops = partition_operands(parts)
+        ops = partition_operands(parts, mesh, axis)
     srcs = jnp.atleast_1d(jnp.asarray(sources, jnp.int32))
     run = _build_multisource_frontier(
         mesh, _axis_key(axis), parts.n_pad, parts.loc_n, cap,
@@ -446,9 +445,9 @@ def _build_multisource_frontier(mesh, axis, n_pad, loc_n, cap, CH, RC, S):
             go = lax.psum(jnp.any(improved).astype(jnp.int32), axis) > 0
             return ND, improved, it + 1, edges, go
 
-        it0 = pvary(jnp.int32(0), axis_tuple(axis))
-        e0 = pvary(jnp.int32(0), axis_tuple(axis))
-        go0 = pvary(jnp.bool_(True), axis_tuple(axis))
+        it0 = varying(jnp.int32(0), axis)
+        e0 = varying(jnp.int32(0), axis)
+        go0 = jnp.bool_(True)       # invariant: the body returns a psum
         D, _, sweeps, edges, go = lax.while_loop(
             cond, body, (D0, fmask0, it0, e0, go0))
         conv = (~go).astype(jnp.int32)

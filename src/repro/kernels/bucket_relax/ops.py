@@ -2,8 +2,8 @@
 
 ``bucket_relax_block`` pads the light in-ELL operands to the kernel grid —
 INF for the distance and weight slots, id 0 for index slots, none of which
-can improve a label or raise a flag — then dispatches and OR-reduces the
-per-block improvement flags.
+can improve a label or raise a flag — gathers the candidate source
+distances, then dispatches and OR-reduces the per-block improvement flags.
 
 ``make_bucket_pull_fn`` adapts it to core/delta_stepping.py's pull
 contract ``pull(dist, ops, hi) -> (new_dist, go)``; the result is
@@ -11,8 +11,8 @@ bitwise-equal to the flat ``make_light_pull_fn`` (same candidate multiset
 plus INF no-ops from padding, and elementwise-exact flag comparisons), so
 ``delta_stepping_kernel`` solves match ``delta_stepping`` bit for bit.
 
-On CPU (this container) ``interpret=True`` executes the kernel body in
-Python; on TPU the same call lowers to Mosaic.  ``auto_interpret()`` picks
+Off the TPU ``interpret=True`` executes the kernel body in Python; on TPU
+the same call lowers to Mosaic.  ``auto_interpret()`` picks
 per-backend so library code stays platform-agnostic.
 """
 from __future__ import annotations
@@ -67,8 +67,11 @@ def bucket_relax_block(
     d = _pad_to(dist, V_pad, 0, INF)
     idx = _pad_to(_pad_to(ell_idx, V_pad, 0, 0), K_pad, 1, 0)
     w = _pad_to(_pad_to(ell_w, V_pad, 0, INF), K_pad, 1, INF)
+    # the row gather stays in XLA (Mosaic lowers only 2-D gathers); the
+    # kernel reads the candidates slot-major, see kernel.py.
     new, flags = K.bucket_relax(
-        d, idx, w, hi, block_v=block_v, block_k=bk, interpret=interpret
+        dist[idx.T], w.T, d, hi, block_v=block_v, block_k=bk,
+        interpret=interpret
     )
     return new[:n], jnp.any(flags > 0)
 

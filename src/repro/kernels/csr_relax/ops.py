@@ -2,11 +2,11 @@
 
 Pad the (n, K) ELL arrays to the block grid — INF-weight slots pointing at
 vertex 0 can never win a min, the same unreachable-padding argument as the
-paper's padded matrix (§III-B.2) — then dispatch and fold the self-distance
-``min(dist, ·)`` back in.
+paper's padded matrix (§III-B.2) — gather the candidate source distances in
+XLA, then dispatch and fold the self-distance ``min(dist, ·)`` back in.
 
-On CPU (this container) ``interpret=True`` executes the kernel body in
-Python; on TPU the same call lowers to Mosaic.  ``auto_interpret()`` picks
+Off the TPU ``interpret=True`` executes the kernel body in Python; on TPU
+the same call lowers to Mosaic.  ``auto_interpret()`` picks
 per-backend so library code stays platform-agnostic.
 """
 from __future__ import annotations
@@ -59,11 +59,12 @@ def csr_relax_sweep(
         bk = next((d for d in range(128, 7, -8) if K8 % d == 0), 128)
     n_pad = _aligned(n, block_v)
     K_pad = _aligned(K8, bk)
-    d = _pad_to(dist, n_pad, 0, INF)
     idx = _pad_to(_pad_to(ell_idx, n_pad, 0, 0), K_pad, 1, 0)
     w = _pad_to(_pad_to(ell_w, n_pad, 0, INF), K_pad, 1, INF)
+    # the row gather stays in XLA (Mosaic lowers only 2-D gathers); the
+    # kernel reads the candidates slot-major, see kernel.py.
     out = K.ell_relax(
-        d, idx, w, block_v=block_v, block_k=bk, interpret=interpret
+        dist[idx.T], w.T, block_v=block_v, block_k=bk, interpret=interpret
     )
     return jnp.minimum(dist, out[:n])
 

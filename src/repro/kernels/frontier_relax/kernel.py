@@ -1,77 +1,63 @@
 """Pallas TPU kernel for frontier-compacted candidate generation.
 
 The frontier engine (core/frontier.py) relaxes only the active vertices'
-out-edges.  The streaming half of that sweep — gather each compacted
-frontier vertex's distance and add it across its padded out-ELL window —
-is dense, regular work over (F, K) blocks, and that is what this kernel
-owns:
+out-edges.  The streaming half of that sweep — add each compacted frontier
+vertex's distance across its padded out-ELL window — is dense, regular
+work over (K, F) blocks, and that is what this kernel owns:
 
-    cand[f, k] = dist[fids[f]] + ell_w[f, k]        (INF when fids[f] == n)
+    cand[k, f] = df[f] + w[k, f]
 
-The scatter-min of ``cand`` into the destination vertices stays outside in
-XLA (``.at[].min``): TPU Pallas has no scatter primitive, and XLA's native
-deterministic scatter lowering is exactly the associative ``atomicMin``
-replacement the other engines already rely on.  The split keeps the kernel
-TPU-legal — the frontier-id gather lowers to the same Mosaic dynamic-gather
-path as kernels/csr_relax's row gather — while the kernel still touches
-only the compacted frontier's edge windows, never the full edge set.
+The gather of the frontier distances ``df = dist[fids]`` (INF past the
+compaction sentinel) runs in XLA (ops.py): Mosaic lowers only 2-D
+gathers, and a resident distance vector would cap n at what VMEM holds.
+The scatter-min of ``cand`` into the destination vertices stays outside
+in XLA too (``.at[].min``): TPU Pallas has no scatter primitive, and XLA's
+native deterministic scatter lowering is exactly the associative
+``atomicMin`` replacement the other engines already rely on.
 
-Grid is (F//bf, K//bk); the dist vector rides along fully resident in VMEM
-(one (1, n) block every step, as in kernels/csr_relax) and each step reads
-its (1, bf) slice of frontier ids.  Sentinel ids (== n, the compaction
-padding) yield INF candidates, which the scatter-min epilogue ignores.
+Operands are slot-major, (K, F) — the physical layout XLA gives a narrow
+(F, K) array on TPU — so each step broadcasts a lane-dense (1, bf) row of
+frontier distances over its (bk, bf) weight block.
 """
 from __future__ import annotations
 
 import functools
 
 import jax
-import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _frontier_cand_kernel(dist_ref, fid_ref, w_ref, out_ref):
-    """dist_ref: (1, n) full vector; fid_ref: (1, bf) int32 frontier ids;
-    w_ref/out_ref: (bf, bk) out-ELL weight / candidate blocks."""
-    d = dist_ref[...][0]                                     # (n,)
-    fid = fid_ref[...][0]                                    # (bf,)
-    n = d.shape[0]
-    df = jnp.where(fid < n, d[jnp.minimum(fid, n - 1)], jnp.inf)
-    out_ref[...] = df[:, None] + w_ref[...]
+def _frontier_cand_kernel(df_ref, w_ref, out_ref):
+    """df_ref: (1, bf) frontier distances; w_ref/out_ref: (bk, bf)
+    out-ELL weight / candidate blocks."""
+    out_ref[...] = df_ref[...] + w_ref[...]
 
 
 @functools.partial(
     jax.jit, static_argnames=("block_f", "block_k", "interpret")
 )
 def frontier_cand(
-    dist: jax.Array,
-    fids: jax.Array,
-    ell_w: jax.Array,
+    df: jax.Array,
+    w: jax.Array,
     *,
     block_f: int = 256,
     block_k: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """dist[fids[f]] + ell_w[f, k] for the compacted frontier (INF past the
-    sentinel).  Requires F % block_f == 0 and K % block_k == 0 (ops.py pads
-    to the grid).  Returns the raw (F, K) candidate block."""
-    n = dist.shape[0]
-    F, K = ell_w.shape
+    """df[f] + w[k, f] for the compacted frontier: df (F,), w (K, F) ->
+    (K, F).  Requires F % block_f == 0 and K % block_k == 0 (ops.py pads
+    to the grid)."""
+    K, F = w.shape
     if block_k is None:
         block_k = K
-    assert fids.shape == (F,), (fids.shape, F)
+    assert df.shape == (F,), (df.shape, F)
     assert F % block_f == 0 and K % block_k == 0, (F, K, block_f, block_k)
-    grid = (F // block_f, K // block_k)
-    out = pl.pallas_call(
+    blk = pl.BlockSpec((block_k, block_f), lambda f, k: (k, f))
+    return pl.pallas_call(
         _frontier_cand_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, n), lambda f, k: (0, 0)),           # full dist
-            pl.BlockSpec((1, block_f), lambda f, k: (0, f)),
-            pl.BlockSpec((block_f, block_k), lambda f, k: (f, k)),
-        ],
-        out_specs=pl.BlockSpec((block_f, block_k), lambda f, k: (f, k)),
-        out_shape=jax.ShapeDtypeStruct((F, K), dist.dtype),
+        grid=(F // block_f, K // block_k),
+        in_specs=[pl.BlockSpec((1, block_f), lambda f, k: (0, f)), blk],
+        out_specs=blk,
+        out_shape=jax.ShapeDtypeStruct((K, F), w.dtype),
         interpret=interpret,
-    )(dist[None, :], fids[None, :], ell_w)
-    return out
+    )(df[None, :], w)

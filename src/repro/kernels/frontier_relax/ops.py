@@ -2,7 +2,8 @@
 
 ``frontier_cand_block`` pads the compacted-frontier operands to the kernel
 grid — sentinel ids (n) for frontier slots, INF for weight slots, both of
-which produce INF candidates the scatter-min ignores — then dispatches.
+which produce INF candidates the scatter-min ignores — gathers the frontier
+distances, then dispatches.
 
 ``make_frontier_sweep_fn`` assembles a full frontier sweep satisfying
 core/frontier.py's sweep contract: an inner ``lax.while_loop`` walks the
@@ -11,8 +12,8 @@ frontier size), gathers each chunk's padded out-ELL windows, generates
 candidates with the kernel, and scatter-mins them in XLA.  Bitwise-equal to
 the flat-CSR default sweep: same candidate multiset plus INF no-ops.
 
-On CPU (this container) ``interpret=True`` executes the kernel body in
-Python; on TPU the same call lowers to Mosaic.  ``auto_interpret()`` picks
+Off the TPU ``interpret=True`` executes the kernel body in Python; on TPU
+the same call lowers to Mosaic.  ``auto_interpret()`` picks
 per-backend so library code stays platform-agnostic.
 """
 from __future__ import annotations
@@ -67,9 +68,12 @@ def frontier_cand_block(
     K_pad = _aligned(K8, bk)
     f = _pad_to(fids, F_pad, 0, n)                   # sentinel -> INF cand
     w = _pad_to(_pad_to(ell_w, F_pad, 0, INF), K_pad, 1, INF)
+    # the frontier-distance gather stays in XLA (Mosaic lowers only 2-D
+    # gathers); the kernel reads slot-major blocks, see kernel.py.
+    df = jnp.where(f < n, dist[jnp.minimum(f, n - 1)], INF)
     out = K.frontier_cand(
-        dist, f, w, block_f=block_f, block_k=bk, interpret=interpret
-    )
+        df, w.T, block_f=block_f, block_k=bk, interpret=interpret
+    ).T
     return out[:F, :Kw]
 
 

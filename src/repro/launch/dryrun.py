@@ -29,7 +29,7 @@ import jax.numpy as jnp
 
 from repro.configs import LONG_CONTEXT_ARCHS, SHAPES, ARCHS, get_config
 from repro.launch import hlo_analysis as H
-from repro.core._compat import set_mesh
+from jax import set_mesh
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import build_cell
 

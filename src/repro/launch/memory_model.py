@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCHS, LONG_CONTEXT_ARCHS, SHAPES, get_config
-from repro.core._compat import set_mesh
+from jax import set_mesh
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import default_grad_accum, default_opt_config
 from repro.models import transformer as T

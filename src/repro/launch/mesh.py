@@ -5,7 +5,7 @@ from __future__ import annotations
 
 import jax
 
-from repro.core._compat import make_mesh
+from repro.core._axes import make_mesh
 
 
 def _mk(shape, axes):

@@ -35,6 +35,7 @@ import numpy as np
 from repro.core import csr as C
 from repro.core.api import shortest_paths
 from repro.dynamic import DynamicGraph
+from repro.launch.runtime import enable_compile_cache
 from repro.serve import (DistanceCache, GraphRegistry, LatencyRecorder,
                          MicroBatchScheduler, MutationEvent, make_churn_trace)
 
@@ -147,6 +148,7 @@ def main(argv=None):
                          "PATH-with-.cost.jsonl; both are schema-"
                          "validated at exit (repro/obs)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     capture = None
     if args.trace_out:

@@ -1,12 +1,3 @@
-import os
-import sys
-
-# Device count must be fixed before jax imports; parse --procs by hand.
-if "--procs" in sys.argv:
-    _n = sys.argv[sys.argv.index("--procs") + 1]
-    os.environ["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={_n} "
-        + os.environ.get("XLA_FLAGS", ""))
 """Paper-reproduction driver: run any SSSP engine on any graph.
 
     PYTHONPATH=src python -m repro.launch.sssp_run \
@@ -23,6 +14,7 @@ gather are included.
 import argparse
 import time
 
+import jax
 import numpy as np
 
 
@@ -60,9 +52,14 @@ def main(argv=None):
     ap.add_argument("--verify", action="store_true")
     args = ap.parse_args(argv)
 
+    from repro.launch.runtime import enable_compile_cache, use_devices
+
+    enable_compile_cache()
+    use_devices(args.procs)
+
     from repro.core import csr as C
     from repro.core import graph as G
-    from repro.core._compat import make_mesh
+    from repro.core._axes import make_mesh
     from repro.core.api import (DELTA_ENGINES, SHARDED_CSR_ENGINES,
                                 shortest_paths)
     from repro.core.serial import dijkstra_serial_np
@@ -92,7 +89,8 @@ def main(argv=None):
     mesh = None
     if args.engine in ("dijkstra_sharded", "bellman_sharded",
                        "multisource") + SHARDED_CSR_ENGINES:
-        mesh = make_mesh((max(args.procs, 1),), ("data",))
+        mesh = make_mesh((args.procs,), ("data",),
+                         devices=jax.devices()[:args.procs])
 
     source = (np.arange(args.sources) % args.nodes
               if args.engine in ("multisource", "multisource_csr")

@@ -20,8 +20,9 @@ occupancy, dedup savings, answers-by-path, cache hit rate.
 is bitwise-equal — the end-to-end form of the serving exactness
 guarantee (tests/test_serve.py holds the per-component forms).
 
-``--devices P`` emulates a P-device mesh (forced host devices, fixed
-before jax initializes — the MPI-procs analogue) and ``--shard-threshold
+``--devices P`` runs on a P-device mesh (emulated host devices on the
+CPU backend — the MPI-procs analogue — and real chips on a TPU host,
+where asking for more than are visible fails) and ``--shard-threshold
 N`` routes graphs with >= N vertices through the vertex-partitioned
 sharded engines (serve/dispatch.py); ``--verify`` covers the sharded
 answers identically, which is how CI's ``--smoke --devices 4`` leg pins
@@ -43,26 +44,6 @@ point.
 """
 from __future__ import annotations
 
-import os
-import sys
-
-# Device count must be fixed before jax initializes; parse --devices by
-# hand (same pattern as benchmarks/run_bench.py).
-if __name__ == "__main__" and "--help" not in sys.argv and "-h" not in sys.argv:
-    _n = 1
-    for _i, _a in enumerate(sys.argv):
-        try:
-            if _a == "--devices":
-                _n = int(sys.argv[_i + 1])
-            elif _a.startswith("--devices="):
-                _n = int(_a.split("=", 1)[1])
-        except (IndexError, ValueError):
-            break
-    if _n > 1:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={_n} "
-            + os.environ.get("XLA_FLAGS", ""))
-
 import argparse
 import time
 
@@ -74,6 +55,7 @@ from repro.serve import (STATUS_OK, STATUSES, DispatchPolicy, DistanceCache,
                          GraphRegistry, LatencyRecorder, MicroBatchScheduler,
                          MutationEvent, QueryRejected, SCENARIOS,
                          make_churn_trace, make_trace, set_default_policy)
+from repro.launch.runtime import enable_compile_cache, use_devices
 from repro.serve.dispatch import DEFAULT_SHARD_THRESHOLD
 
 
@@ -326,8 +308,8 @@ def main(argv=None):
                     help="ALT landmarks per graph (0 disables)")
     ap.add_argument("--cache-rows", type=int, default=256)
     ap.add_argument("--devices", type=int, default=1,
-                    help="mesh size for the sharded route (host devices "
-                         "are forced before jax init; 1 = never shard)")
+                    help="mesh size for the sharded route (emulated host "
+                         "devices on CPU; 1 = never shard)")
     ap.add_argument("--shard-threshold", type=int,
                     default=DEFAULT_SHARD_THRESHOLD,
                     help="route graphs with >= this many vertices through "
@@ -363,6 +345,8 @@ def main(argv=None):
                          "out-of-support queries still fall back to "
                          "them")
     args = ap.parse_args(argv)
+    enable_compile_cache()
+    use_devices(args.devices)
 
     capture = None
     if args.trace_out:

@@ -56,7 +56,7 @@ def main(argv=None):
                          "gradient compression")
     args = ap.parse_args(argv)
 
-    from repro.core._compat import set_mesh
+    from jax import set_mesh
     from repro.checkpoint import CheckpointManager, latest_step, restore_checkpoint
     from repro.configs import get_config, make_smoke
     from repro.data.pipeline import DataConfig, SyntheticPipeline

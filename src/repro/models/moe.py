@@ -26,10 +26,11 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
+from jax.sharding import get_abstract_mesh
 
 from repro.models import common as cm
 from repro.models.mlp import init_mlp, mlp
-from repro.core._compat import get_abstract_mesh, shard_map as _shard_map
 from repro.sharding.rules import constrain, dp_size
 
 

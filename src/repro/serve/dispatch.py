@@ -61,8 +61,8 @@ KINDS = ("single", "batch", "p2p")
 @functools.lru_cache(maxsize=None)
 def serving_mesh(nprocs: int, axis: str = "data") -> jax.sharding.Mesh:
     """The serving layer's cached 1-D mesh over the first ``nprocs``
-    devices (forced host devices in CI/benchmarks, real ones on metal)."""
-    from repro.core._compat import make_mesh
+    devices (forced host devices in CI and tests, chips on a TPU host)."""
+    from repro.core._axes import make_mesh
 
     return make_mesh((nprocs,), (axis,), devices=jax.devices()[:nprocs])
 
@@ -104,7 +104,7 @@ class DispatchPolicy:
     shard_threshold: vertex count at which graphs route sharded
         (inclusive).  ``None`` disables sharding outright.
     nprocs: devices to partition across; default = every visible device.
-        Clamped to the visible count; 1 also disables sharding.
+        More than are visible raises ``ValueError``; 1 disables sharding.
     axis: mesh axis name (matches the sharded engines' default).
     delta_threshold: vertex count at which non-sharded single-source
         solves on static CsrGraphs route to the Δ-stepping engine
@@ -116,7 +116,11 @@ class DispatchPolicy:
                  nprocs: int | None = None, axis: str = "data",
                  delta_threshold: int | None = DEFAULT_DELTA_THRESHOLD):
         avail = len(jax.devices())
-        self.nprocs = avail if nprocs is None else min(int(nprocs), avail)
+        self.nprocs = avail if nprocs is None else int(nprocs)
+        if not 1 <= self.nprocs <= avail:
+            raise ValueError(
+                f"nprocs={self.nprocs} needs 1..{avail} devices; "
+                f"{avail} are visible ({jax.devices()[0].platform})")
         self.shard_threshold = shard_threshold
         self.delta_threshold = delta_threshold
         self.axis = axis

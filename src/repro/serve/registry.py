@@ -170,15 +170,17 @@ class GraphHandle:
             self._partition_nprocs = nprocs
         return self._partition
 
-    def partition_ops(self, nprocs: int) -> dict:
-        """Staged per-owner device arrays over :meth:`partition` —
-        memoized like the other operand pytrees so every sharded solve
-        after the first skips the host->device upload."""
-        parts = self.partition(nprocs)
-        if self._partition_ops is None:
-            from repro.core.sharded_csr import partition_operands
+    def partition_ops(self, mesh, axis: str = "data") -> dict:
+        """Staged per-owner device arrays over :meth:`partition`, each
+        owner's block on its own device of ``mesh`` — memoized like the
+        other operand pytrees so every sharded solve after the first
+        skips the host->device upload."""
+        from repro.core._axes import axis_size
+        from repro.core.sharded_csr import partition_operands
 
-            self._partition_ops = partition_operands(parts)
+        parts = self.partition(axis_size(mesh, axis))
+        if self._partition_ops is None:
+            self._partition_ops = partition_operands(parts, mesh, axis)
         return self._partition_ops
 
     def multisource_sweep_fn(self):

@@ -691,7 +691,7 @@ class MicroBatchScheduler:
                 with tr.span("stage", graph=handle.name):
                     self._probe("stage", handle.name)
                     parts = handle.partition(choice.nprocs)
-                    pops = handle.partition_ops(choice.nprocs)
+                    pops = handle.partition_ops(choice.mesh, choice.axis)
                     self.registry.touch_staged(handle.name)
                 self._probe("solve", handle.name)
                 ms = self._sweep_cap(handle.name)
@@ -793,7 +793,7 @@ class MicroBatchScheduler:
                 with tr.span("stage", graph=handle.name):
                     self._probe("stage", handle.name)
                     parts = handle.partition(choice.nprocs)
-                    pops = handle.partition_ops(choice.nprocs)
+                    pops = handle.partition_ops(choice.mesh, choice.axis)
                     self.registry.touch_staged(handle.name)
                 self._probe("solve", handle.name)
                 ms = self._sweep_cap(handle.name)

@@ -22,9 +22,7 @@ import math
 from typing import Any, Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding
-
-from repro.core._compat import get_abstract_mesh
+from jax.sharding import Mesh, NamedSharding, get_abstract_mesh
 from jax.sharding import PartitionSpec as P
 
 

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.core._compat import shard_map
+from jax import shard_map
 from repro.models import transformer as T
 from repro.train import compression as comp
 from repro.train.optimizer import OptConfig, adamw_update

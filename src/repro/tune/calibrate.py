@@ -27,27 +27,6 @@ fitted from it refuse to replay logs from a different backend
 """
 from __future__ import annotations
 
-import os
-import sys
-
-# Device count must be fixed before jax initializes; parse --devices by
-# hand (same pattern as benchmarks/run_bench.py).
-_DEFAULT_DEVICES = 1
-if __name__ == "__main__" and "--help" not in sys.argv and "-h" not in sys.argv:
-    _n = _DEFAULT_DEVICES
-    for _i, _a in enumerate(sys.argv):
-        try:
-            if _a == "--devices":
-                _n = int(sys.argv[_i + 1])
-            elif _a.startswith("--devices="):
-                _n = int(_a.split("=", 1)[1])
-        except (IndexError, ValueError):
-            break
-    if _n > 1:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={_n} "
-            + os.environ.get("XLA_FLAGS", ""))
-
 import argparse
 import json
 import platform
@@ -139,13 +118,9 @@ def sweep(grid, *, repeats: int = 3, devices: int = 1,
         BATCHES_SMOKE if smoke else BATCHES_FULL)
     mesh = None
     if devices > 1:
-        if jax.device_count() < devices:
-            raise SystemExit(
-                f"--devices {devices} needs {devices} XLA devices but only "
-                f"{jax.device_count()} exist (run via `python -m "
-                f"repro.tune.calibrate`, which forces the host count)")
-        from repro.core._compat import make_mesh
-        mesh = make_mesh((devices,), ("data",))
+        from repro.core._axes import make_mesh
+        from repro.launch.runtime import use_devices
+        mesh = make_mesh((devices,), ("data",), devices=use_devices(devices))
 
     log = CostLog()
     prev = set_cost_log(log)
@@ -244,10 +219,13 @@ if __name__ == "__main__":
     ap.add_argument("--smoke", action="store_true",
                     help="CI-sized grid (< ~1 min on CPU)")
     ap.add_argument("--repeats", type=int, default=3)
-    ap.add_argument("--devices", type=int, default=_DEFAULT_DEVICES,
-                    help="mesh size for the sharded engines (forced host "
-                         "device count on CPU); 1 drops the sharded leg")
+    ap.add_argument("--devices", type=int, default=1,
+                    help="mesh size for the sharded engines (emulated host "
+                         "devices on CPU); 1 drops the sharded leg")
     ap.add_argument("--out", default=DEFAULT_OUT)
     args = ap.parse_args()
+    from repro.launch.runtime import enable_compile_cache, use_devices
+    enable_compile_cache()
+    use_devices(args.devices)
     run(args.smoke, repeats=args.repeats, devices=args.devices,
         out=args.out)

@@ -8,11 +8,9 @@ import heapq
 import numpy as np
 import pytest
 
-# NOTE: the old ``requires_modern_jax_sharding`` gate is gone — the sharded
-# engines, training substrate, and their tests all go through
-# repro.core._compat now, which provides shard_map / set_mesh /
-# make_mesh / abstract_mesh on both the pinned jax 0.4.37 and modern jax,
-# so those 13 tests run everywhere.
+# The sharded engines and their tests call jax's sharding API directly
+# (jax >= 0.9: jax.shard_map, lax.pcast, jax.set_mesh); meshes come from
+# repro.core._axes.make_mesh, which gives them Auto axes.
 
 
 @pytest.fixture

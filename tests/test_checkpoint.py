@@ -71,7 +71,7 @@ def test_shape_mismatch_raises(tmp_path):
 
 def test_restore_with_shardings(tmp_path):
     """Reshard-on-load: restore with explicit NamedShardings."""
-    from repro.core._compat import make_mesh
+    from repro.core._axes import make_mesh
     from repro.sharding import rules
     mesh = make_mesh((1,), ("data",))
     st = _state()

@@ -101,7 +101,8 @@ def test_collectives_counted_in_spmd_module():
     import functools
     from jax.sharding import PartitionSpec as P
 
-    from repro.core._compat import make_mesh, shard_map
+    from jax import shard_map
+    from repro.core._axes import make_mesh
     mesh = make_mesh((1,), ("data",))
 
     @functools.partial(shard_map, mesh=mesh, in_specs=P("data"),

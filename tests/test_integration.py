@@ -39,7 +39,7 @@ import jax, numpy as np, jax.numpy as jnp
 from repro.core import graph as G
 from repro.core.api import shortest_paths
 from repro.core.serial import dijkstra_serial_np
-from repro.core._compat import make_mesh
+from repro.core._axes import make_mesh
 mesh = make_mesh((8,), ("data",))
 g = G.random_graph(103, 400, seed=5)
 ref, _ = dijkstra_serial_np(g.adj, 4)
@@ -65,7 +65,7 @@ import jax, numpy as np, jax.numpy as jnp
 from repro.core import graph as G
 from repro.core.sharded import dijkstra_sharded
 from repro.core.serial import dijkstra_serial_np
-from repro.core._compat import make_mesh
+from repro.core._axes import make_mesh
 mesh = make_mesh((8,), ("data",))
 g = G.random_graph(96, 380, seed=8).padded(8)
 ref, _ = dijkstra_serial_np(g.adj, 0)
@@ -119,7 +119,7 @@ from repro.train.optimizer import OptConfig, init_opt_state
 from repro.train import compression as comp
 cfg = make_smoke(get_config("qwen1.5-0.5b"))
 opt = OptConfig(lr=1e-3, warmup_steps=1, total_steps=30)
-from repro.core._compat import make_mesh
+from repro.core._axes import make_mesh
 mesh = make_mesh((4,), ("data",))
 key = jax.random.PRNGKey(0)
 st = init_train_state(key, cfg, opt)
@@ -186,7 +186,8 @@ from repro.configs import get_config, make_smoke
 from repro.models.moe import init_moe, moe
 cfg = dataclasses.replace(make_smoke(get_config("qwen2-moe-a2.7b")),
                           expert_pad_to=8)
-from repro.core._compat import make_mesh, set_mesh
+from jax import set_mesh
+from repro.core._axes import make_mesh
 mesh = make_mesh((2, 2), ("data", "model"))
 p = init_moe(jax.random.PRNGKey(0), cfg)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model))

@@ -27,7 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core._compat import make_mesh, shard_map
+from jax import shard_map
+from repro.core._axes import make_mesh
 from repro.core.sharded import minloc_allgather, minloc_packed, minloc_pmin
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -96,7 +97,8 @@ import functools
 import numpy as np
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.core._compat import make_mesh, shard_map
+from jax import shard_map
+from repro.core._axes import make_mesh
 from repro.core.sharded import minloc_allgather, minloc_packed, minloc_pmin
 
 I32_MAX = np.iinfo(np.int32).max

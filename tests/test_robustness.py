@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from repro.core import csr as C
-from repro.core._compat import make_mesh
+from repro.core._axes import make_mesh
 from repro.core.api import shortest_paths
 from repro.dynamic import DynamicGraph
 from repro.serve import (DistanceCache, FaultPlan, GraphRegistry,

@@ -20,7 +20,7 @@ import jax
 
 from conftest import dijkstra_oracle
 from repro.core import csr as C
-from repro.core._compat import make_mesh
+from repro.core._axes import make_mesh
 from repro.core.api import shortest_paths
 from repro.serve import (DispatchPolicy, DistanceCache, GraphRegistry,
                          MicroBatchScheduler)
@@ -49,8 +49,11 @@ def test_policy_would_shard_is_pure_size_check():
 
 
 def test_policy_clamps_nprocs_to_visible_devices():
-    pol = DispatchPolicy(nprocs=10**6)
-    assert pol.nprocs == NDEV
+    # asking for more devices than are visible is an error, never a
+    # silent clamp to fewer
+    with pytest.raises(ValueError, match="visible"):
+        DispatchPolicy(nprocs=NDEV + 1)
+    assert DispatchPolicy(nprocs=NDEV).nprocs == NDEV
     assert DispatchPolicy(nprocs=1).nprocs == 1
 
 
@@ -169,12 +172,14 @@ def test_registry_partition_staging_memoized_and_accounted():
     parts = h.partition(2)
     assert parts is h.partition(2)               # memoized per nprocs
     assert reg.bytes_in_use >= base + parts.nbytes
-    ops = h.partition_ops(2)
-    assert ops is h.partition_ops(2)
+    mesh = serving_mesh(1)
+    parts = h.partition(1)
+    ops = h.partition_ops(mesh)
+    assert ops is h.partition_ops(mesh)
     assert reg.bytes_in_use > base + parts.nbytes  # device arrays counted
     # a different arity restages (policy change, not the serving path)
     assert h.partition(4).nprocs == 4
-    assert h.partition_ops(4) is not ops
+    assert h.partition_ops(mesh) is not ops
 
 
 def test_registry_partition_refuses_dynamic_graphs():

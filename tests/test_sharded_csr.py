@@ -19,7 +19,7 @@ import jax
 
 from conftest import dijkstra_oracle
 from repro.core import csr as C
-from repro.core._compat import make_mesh
+from repro.core._axes import make_mesh
 from repro.core.api import shortest_paths
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -141,7 +141,7 @@ def test_sharded_csr_engines_need_mesh():
 _MULTIDEV_CODE = """
 import numpy as np
 from repro.core import csr as C
-from repro.core._compat import make_mesh
+from repro.core._axes import make_mesh
 from repro.core.api import shortest_paths
 
 P = {procs}

@@ -6,10 +6,10 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config, make_smoke
-from repro.core._compat import abstract_mesh
 from repro.data.pipeline import DataConfig, SyntheticPipeline
 from repro.sharding import rules
 from repro.train import compression as comp
@@ -107,7 +107,8 @@ def test_quantize_roundtrip_error_bound():
 def test_error_feedback_preserves_signal():
     """Sum of dequantized transmissions + final error == sum of inputs
     (error feedback never loses gradient mass)."""
-    from repro.core._compat import make_mesh, shard_map
+    from jax import shard_map
+    from repro.core._axes import make_mesh
     mesh = make_mesh((1,), ("data",))
     import functools
     from jax.sharding import PartitionSpec as P
@@ -159,7 +160,7 @@ def test_data_per_host_sharding():
 # ---------------------------------------------------------------------------
 
 def test_assign_spec_divisibility_fallback():
-    mesh = abstract_mesh((2, 4), ("data", "model"))
+    mesh = AbstractMesh((2, 4), ("data", "model"))
     # divisible -> assigned
     assert rules.assign_spec((8, 16), [["dp"], ["tp"]], mesh) == P("data", "model")
     # first dim indivisible -> dropped, second still assigned
@@ -170,7 +171,7 @@ def test_assign_spec_divisibility_fallback():
 
 def test_param_rules_moe_fallback():
     # production model axis is 16-way: 60 experts are indivisible
-    mesh = abstract_mesh((2, 16), ("data", "model"))
+    mesh = AbstractMesh((2, 16), ("data", "model"))
     # 60 experts indivisible by 16 -> ff gets the model axis
     import jax.tree_util as jtu
     path = (jtu.DictKey("segments"), jtu.SequenceKey(0), jtu.SequenceKey(0),
@@ -183,7 +184,7 @@ def test_param_rules_moe_fallback():
 
 
 def test_cache_spec_long_context_batch1():
-    mesh = abstract_mesh((2, 4), ("data", "model"))
+    mesh = AbstractMesh((2, 4), ("data", "model"))
     # (rep, B=1, S, KV, hd): B unshardable -> S takes dp, KV takes tp
     spec = rules.cache_spec((26, 1, 1024, 4, 256), mesh)
     assert spec == P(None, None, "data", "model", None)
