@@ -1,0 +1,8 @@
+"""Mean ``sweeps`` payload of the traced batch solve spans."""
+from bench import spans
+
+
+def read(ctx):
+    s = [sp.args["sweeps"] for sp in spans.solves(ctx["spans"], "rows")
+         if "sweeps" in sp.args]
+    return sum(s) / len(s) if s else None
