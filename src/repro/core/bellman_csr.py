@@ -160,7 +160,9 @@ def sssp_multisource_csr(
     the max over sources).  ``converged`` is the joint flag — False means
     at least one row may sit above its fixpoint (same guardrail contract
     as :func:`sssp_bellman_csr`).  pred is recovered on demand —
-    api.recover_pred reuses the O(m) recovery per row."""
+    api.recover_pred reuses the O(m) recovery per row.  The sweep and the
+    convergence test carry the ``jax.named_scope`` names
+    ``multisource_csr.relax`` and ``multisource_csr.test``."""
     mark_trace("multisource_csr")
     cap = n if max_sweeps is None else max_sweeps
     sweep = sweep_fn or segment_relax_sweep_multi
@@ -168,16 +170,20 @@ def sssp_multisource_csr(
 
     def cond(carry):
         D, prev, it = carry
-        return (it < cap) & jnp.any(D != prev)
+        with jax.named_scope("multisource_csr.test"):
+            return (it < cap) & jnp.any(D != prev)
 
     def body(carry):
         D, _, it = carry
-        new = jnp.minimum(sweep(D, csr), D)
+        with jax.named_scope("multisource_csr.relax"):
+            new = jnp.minimum(sweep(D, csr), D)
         return new, D, it + 1
 
     prev0 = jnp.full_like(D0, -1.0)
     D, prev, sweeps = lax.while_loop(cond, body, (D0, prev0, jnp.int32(0)))
-    return D, sweeps, ~jnp.any(D != prev)
+    with jax.named_scope("multisource_csr.test"):
+        converged = ~jnp.any(D != prev)
+    return D, sweeps, converged
 
 
 def predecessors_from_dist_csr(dist: jax.Array, csr: dict, source) -> jax.Array:

@@ -59,6 +59,12 @@ The kernel path (api engine ``frontier_kernel``) swaps the inner chunk
 relax for the Pallas candidate kernel in kernels/frontier_relax, which
 streams the compacted frontier's padded out-ELL windows (CsrGraph.out_ell)
 in fixed-size row blocks.
+
+The stages carry stable ``jax.named_scope`` names, which a profiler trace
+keeps in each device op's ``tf_op`` path: ``frontier.compact`` (step 1,
+and the gather of the frontier rows' distances), ``frontier.relax`` (the
+edge-slot walk of steps 2–3) and ``frontier.test`` (the stopping rule and
+the pending-set update).
 """
 from __future__ import annotations
 
@@ -135,7 +141,8 @@ def _slot_minloop(nd, starts, off, E, m, F, *, chunk: int, emit,
         cand, tgt = emit(row, pos, valid)
         return scatter(nd2, tgt, cand), c + 1
 
-    nd, _ = lax.while_loop(cond, body, (nd, jnp.int32(0)))
+    with jax.named_scope("frontier.relax"):
+        nd, _ = lax.while_loop(cond, body, (nd, jnp.int32(0)))
     return nd
 
 
@@ -252,7 +259,9 @@ def make_flat_sweep_fn(chunk: int = 1024) -> Callable:
         # tests/test_obs.py pins at zero across repeat ticks/versions
         mark_trace("flat_sweep")
         n = dist.shape[0]
-        row_dist = dist[jnp.minimum(fids, n - 1)]   # sentinel rows: 0 slots
+        with jax.named_scope("frontier.compact"):
+            # sentinel rows: 0 slots
+            row_dist = dist[jnp.minimum(fids, n - 1)]
         return relax_edge_slots(
             dist, row_dist, starts, off, E, ops["out_dst"], ops["out_w"],
             chunk=chunk, drop_id=jnp.int32(n),
@@ -273,12 +282,13 @@ def relax_active(ops: dict, dist, active, *, n: int, sweep: Callable):
     called inside jit.  Returns ``(new_dist, E)`` with E the total
     out-degree of the active set (the edges-relaxed increment).
     """
-    fids = jnp.nonzero(active, size=n, fill_value=n)[0].astype(jnp.int32)
-    fcount = jnp.sum(active)
-    starts = ops["out_indptr"][fids]
-    degs = ops["out_indptr"][fids + 1] - starts
-    csum = jnp.cumsum(degs)
-    E, off = csum[-1], csum - degs
+    with jax.named_scope("frontier.compact"):
+        fids = jnp.nonzero(active, size=n, fill_value=n)[0].astype(jnp.int32)
+        fcount = jnp.sum(active)
+        starts = ops["out_indptr"][fids]
+        degs = ops["out_indptr"][fids + 1] - starts
+        csum = jnp.cumsum(degs)
+        E, off = csum[-1], csum - degs
     new = sweep(dist, fids, starts, off, E, fcount, ops)
     return new, E
 
@@ -356,6 +366,7 @@ def frontier_fixpoint(
     """
     limit0 = jnp.float32(0.0 if delta is None else delta)
 
+    @jax.named_scope("frontier.test")
     def settled_or_done(dist, pending):
         done = ~jnp.any(pending)
         if target is not None:
@@ -384,8 +395,9 @@ def frontier_fixpoint(
             limit = jnp.where(has, limit, nxt)
             active = pending & (dist <= limit)
         new, E = relax_active(ops, dist, active, n=n, sweep=sweep)
-        improved = new < dist
-        pending = (pending & ~active) | improved
+        with jax.named_scope("frontier.test"):
+            improved = new < dist
+            pending = (pending & ~active) | improved
         return new, pending, limit, it + 1, edges + E
 
     dist, pending, _, sweeps, edges = lax.while_loop(

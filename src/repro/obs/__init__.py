@@ -1,11 +1,12 @@
 """Observability layer: unified metrics, solve-level tracing, cost records.
 
-- `repro.obs.metrics` — process-local counter/gauge/histogram registry
+- `repro.obs.metrics` — process-local counter/gauge registry
   with labeled series and a deterministic `snapshot()` contract; the
   serving components' `stats()` dicts are views over it.
-- `repro.obs.trace` — hierarchical spans (tick → batch_solve/p2p_solve/
-  repair/stage/mutate) with Chrome-trace + JSONL export, an injected
-  clock, and a no-op singleton when disabled.
+- `repro.obs.trace` — hierarchical spans (tick → batch_solve/p2p_solve
+  → stage/launch/wait/fetch; repair, mutate) with Chrome-trace + JSONL
+  export, ``sssp.*`` annotations on the JAX profiler's trace, an
+  injected clock, and a no-op singleton when disabled.
 - `repro.obs.profile` — per-solve cost records
   ``(engine, statics, shape) → wall_ms, sweeps, edges``, the training
   data for ROADMAP item 4's measured cost model.
@@ -18,12 +19,9 @@ from .capture import cost_path_for, finalize_capture, install_capture
 from .metrics import (
     Counter,
     Gauge,
-    Histogram,
     MetricsRegistry,
-    count_traces,
     default_registry,
     mark_trace,
-    trace_count,
 )
 from .profile import (CostLog, CostRecord, NULL_COST_LOG, backend_info,
                       get_cost_log, set_cost_log)
@@ -35,12 +33,9 @@ __all__ = [
     "install_capture",
     "Counter",
     "Gauge",
-    "Histogram",
     "MetricsRegistry",
-    "count_traces",
     "default_registry",
     "mark_trace",
-    "trace_count",
     "backend_info",
     "CostLog",
     "CostRecord",

@@ -1,4 +1,4 @@
-"""Process-local metrics registry: counters, gauges, histograms.
+"""Process-local metrics registry: counters and gauges.
 
 One uniform namespace for every counter the serving stack keeps —
 `DistanceCache`, `GraphRegistry`, and `MicroBatchScheduler` all hang
@@ -30,11 +30,9 @@ from typing import Callable, Dict, Iterator, Optional, Tuple
 __all__ = [
     "Counter",
     "Gauge",
-    "Histogram",
     "MetricsRegistry",
     "default_registry",
     "mark_trace",
-    "count_traces",
 ]
 
 
@@ -96,61 +94,6 @@ class Gauge:
         return self._value
 
 
-class Histogram:
-    """Streaming distribution summary.
-
-    Keeps every observation (serving runs are bounded, and the latency
-    recorder needs exact p50/p99), exposing count/sum/min/max and
-    percentile helpers.  `snapshot()` reports only the count — the
-    observed values themselves are typically wall-times and would break
-    snapshot determinism.
-    """
-
-    __slots__ = ("name", "labels", "_values")
-
-    def __init__(self, name: str, labels: Tuple[Tuple[str, str], ...] = ()):
-        self.name = name
-        self.labels = labels
-        self._values: list = []
-
-    def observe(self, v: float) -> None:
-        self._values.append(float(v))
-
-    @property
-    def count(self) -> int:
-        return len(self._values)
-
-    @property
-    def sum(self) -> float:
-        return float(sum(self._values))
-
-    @property
-    def min(self) -> float:
-        return min(self._values) if self._values else 0.0
-
-    @property
-    def max(self) -> float:
-        return max(self._values) if self._values else 0.0
-
-    def percentile(self, q: float) -> float:
-        """Nearest-rank percentile over everything observed so far."""
-        if not self._values:
-            return 0.0
-        xs = sorted(self._values)
-        idx = min(len(xs) - 1, max(0, int(round(q / 100.0 * (len(xs) - 1)))))
-        return xs[idx]
-
-    def summary(self) -> Dict[str, float]:
-        return {
-            "count": self.count,
-            "sum": self.sum,
-            "min": self.min,
-            "max": self.max,
-            "p50": self.percentile(50.0),
-            "p99": self.percentile(99.0),
-        }
-
-
 class MetricsRegistry:
     """Get-or-create store of named, labeled series."""
 
@@ -180,16 +123,6 @@ class MetricsRegistry:
             raise TypeError(f"series {name!r} already registered as {type(s).__name__}")
         return s
 
-    def histogram(self, name: str, **labels: str) -> Histogram:
-        key = self._key(name, labels)
-        s = self._series.get(key)
-        if s is None:
-            s = Histogram(name, key[1])
-            self._series[key] = s
-        elif not isinstance(s, Histogram):
-            raise TypeError(f"series {name!r} already registered as {type(s).__name__}")
-        return s
-
     def series(self) -> Iterator[object]:
         return iter(self._series.values())
 
@@ -200,9 +133,7 @@ class MetricsRegistry:
     def snapshot(self) -> Dict[str, float]:
         """Flat, sorted, deterministic view of every series.
 
-        Counters report their count, gauges their current value,
-        histograms their observation count (values may be wall-times
-        and are deliberately excluded — see class docstring).
+        Counters report their count, gauges their current value.
         """
         out: Dict[str, float] = {}
         for (name, labels), s in self._series.items():
@@ -211,8 +142,6 @@ class MetricsRegistry:
                 out[q] = s.value
             elif isinstance(s, Gauge):
                 out[q] = s.value
-            elif isinstance(s, Histogram):
-                out[q + ".count"] = s.count
         return dict(sorted(out.items()))
 
     def reset(self) -> None:
@@ -222,8 +151,6 @@ class MetricsRegistry:
             elif isinstance(s, Gauge):
                 if s._fn is None:
                     s.set(0.0)
-            elif isinstance(s, Histogram):
-                s._values.clear()
 
 
 _DEFAULT: MetricsRegistry = MetricsRegistry()
@@ -247,29 +174,3 @@ def mark_trace(fn_name: str) -> None:
     (re)trace and costs nothing on cached executions.
     """
     _DEFAULT.counter("jit.retrace", fn=fn_name).inc()
-
-
-def trace_count(fn_name: str) -> int:
-    """How many times ``fn_name`` has been traced so far."""
-    return _DEFAULT.counter("jit.retrace", fn=fn_name).value
-
-
-def count_traces(fn_name: str) -> Callable:
-    """Wrap a to-be-jitted callable so each trace of it is counted.
-
-    Used on the sweep functions returned by the memoized kernel
-    factories: the factory's ``lru_cache`` keeps the wrapper's identity
-    stable, so wrapping does not itself cause retraces.
-    """
-
-    def deco(fn: Callable) -> Callable:
-        def wrapper(*args, **kwargs):
-            mark_trace(fn_name)
-            return fn(*args, **kwargs)
-
-        wrapper.__name__ = getattr(fn, "__name__", fn_name)
-        wrapper.__qualname__ = wrapper.__name__
-        wrapper.__wrapped__ = fn
-        return wrapper
-
-    return deco

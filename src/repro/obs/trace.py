@@ -4,13 +4,23 @@ Span taxonomy (parent → child):
 
     tick ─┬─ mutate        graph mutation applied ahead of solves
           ├─ repair        incremental distance repair after a mutation
-          ├─ stage         registry staging of device operands
           ├─ batch_solve   one multisource engine solve (args.qids)
-          └─ p2p_solve     one target= early-exit solve (args.qids)
+          ├─ p2p_solve     one target= early-exit solve (args.qids)
+          │      └─ each solve ─┬─ stage   registry staging of operands
+          │                     ├─ launch  the engine call, to its return
+          │                     ├─ wait    until its outputs are ready
+          │                     └─ fetch   a device→host read (args.bytes)
+          └─ fetch         the p2p row read, after its solve
 
 plus instant events ``submit`` (query admitted) and ``answer`` (answer
 emitted), so an exact answer's chain submit → tick → solve → answer is
 reconstructible from timestamps + qids alone (`obs.validate`).
+
+Each span of a `Tracer` is also a ``jax.profiler.TraceAnnotation`` named
+``sssp.<span name>``, opened and closed with it: under the JAX profiler
+the spans sit on the host plane of the device trace, on the trace's own
+clock, so a device idle gap can be named by the program span it falls
+in.
 
 Two hard requirements drive the shape:
 
@@ -18,10 +28,11 @@ Two hard requirements drive the shape:
   module-level no-op singleton; hot-path call sites guard payload
   construction behind ``if tracer.enabled:`` and the no-op ``span()``
   returns one shared reusable context manager — no allocation, no
-  clock read.
+  clock read, no profiler annotation.
 - **Deterministic under test.**  The clock is injected
   (``Tracer(clock=...)``), fault-plan style, so span ordering and
-  durations are exact in tests.
+  durations are exact in tests; ``jax.profiler`` is imported only when
+  the first span opens.
 """
 from __future__ import annotations
 
@@ -36,7 +47,19 @@ __all__ = [
     "NULL_TRACER",
     "get_tracer",
     "set_tracer",
+    "ANNOTATION_PREFIX",
 ]
+
+ANNOTATION_PREFIX = "sssp."
+
+
+def _annotate(name: str):
+    """A started profiler annotation ``sssp.<name>``."""
+    from jax.profiler import TraceAnnotation
+
+    ann = TraceAnnotation(ANNOTATION_PREFIX + name)
+    ann.__enter__()
+    return ann
 
 
 class Span:
@@ -69,13 +92,15 @@ class Span:
 
 
 class _SpanCtx:
-    """Context manager that closes its span on exit."""
+    """Context manager that closes its span, and its profiler
+    annotation, on exit."""
 
-    __slots__ = ("_tracer", "span")
+    __slots__ = ("_tracer", "span", "_ann")
 
-    def __init__(self, tracer: "Tracer", span: Span):
+    def __init__(self, tracer: "Tracer", span: Span, ann):
         self._tracer = tracer
         self.span = span
+        self._ann = ann
 
     def set(self, **kwargs: Any) -> "_SpanCtx":
         self.span.set(**kwargs)
@@ -85,6 +110,7 @@ class _SpanCtx:
         return self
 
     def __exit__(self, *exc) -> None:
+        self._ann.__exit__(None, None, None)
         self._tracer._close(self.span)
 
 
@@ -124,7 +150,7 @@ class Tracer:
         if args:
             s.args.update(args)
         self._stack.append(s)
-        return _SpanCtx(self, s)
+        return _SpanCtx(self, s, _annotate(name))
 
     def _close(self, span: Span) -> None:
         span.t1 = self._clock()

@@ -94,6 +94,7 @@ import dataclasses
 import time
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -112,6 +113,17 @@ from repro.serve.registry import GraphRegistry
 
 VIAS = ("trivial", "cache", "landmark", "batch", "target", "mutate",
         "degraded", "error")
+
+
+def _fetch(tr, x):
+    """Device→host read of ``x`` (an array, or a tuple of arrays copied in
+    one batch) under a ``fetch`` span that records the bytes read."""
+    with tr.span("fetch") as sp:
+        got = jax.device_get(x)
+        if tr.enabled:
+            sp.set(bytes=sum(np.asarray(a).nbytes
+                             for a in jax.tree_util.tree_leaves(got)))
+    return got
 
 
 @dataclasses.dataclass
@@ -696,9 +708,13 @@ class MicroBatchScheduler:
                 self._probe("solve", handle.name)
                 ms = self._sweep_cap(handle.name)
                 t0 = time.perf_counter() if obs else 0.0
-                d, sw, e, conv = sssp_frontier_sharded(
-                    parts, q.source, choice.mesh, axis=choice.axis,
-                    ops=pops, max_sweeps=ms)
+                with tr.span("launch"):
+                    d, sw, e, conv = sssp_frontier_sharded(
+                        parts, q.source, choice.mesh, axis=choice.axis,
+                        ops=pops, max_sweeps=ms)
+                with tr.span("wait"):
+                    jax.block_until_ready((d, sw, e, conv))
+                sw, e, conv = _fetch(tr, (sw, e, conv))
                 conv = bool(int(conv))
                 self._c["target_solves"].inc()
                 self._c["sharded_p2p"].inc()
@@ -719,7 +735,7 @@ class MicroBatchScheduler:
                 raise NotConverged(
                     f"sharded p2p solve on {handle.name!r} capped at "
                     f"max_sweeps={ms}")
-            row = np.asarray(d)[:handle.n]
+            row = _fetch(tr, d)[:handle.n]
             self.cache.put(self._row_key(handle, q.source), row)
             return Answer(q, float(row[q.target]), "target")
         with tr.span("p2p_solve", qids=(q.qid,)) as sp:
@@ -743,11 +759,15 @@ class MicroBatchScheduler:
             if choice.chunk is not None:
                 skw["chunk"] = int(choice.chunk)
             t0 = time.perf_counter() if obs else 0.0
-            d, _, sw, e, conv = sssp_frontier(
-                ops, jnp.int32(q.source), n=handle.n,
-                sweep_fn=handle.frontier_sweep_fn(), max_sweeps=ms,
-                target=jnp.int32(q.target), target_lb=lb, **skw,
-            )
+            with tr.span("launch"):
+                d, _, sw, e, conv = sssp_frontier(
+                    ops, jnp.int32(q.source), n=handle.n,
+                    sweep_fn=handle.frontier_sweep_fn(), max_sweeps=ms,
+                    target=jnp.int32(q.target), target_lb=lb, **skw,
+                )
+            with tr.span("wait"):
+                jax.block_until_ready((d, sw, e, conv))
+            sw, e, conv = _fetch(tr, (sw, e, conv))
             conv = bool(conv)
             self._c["target_solves"].inc()
             if obs:
@@ -764,7 +784,8 @@ class MicroBatchScheduler:
             raise NotConverged(
                 f"p2p solve on {handle.name!r} capped at max_sweeps={ms} "
                 "before the target settled")
-        return Answer(q, float(np.asarray(d)[q.target]), "target")
+        # the whole partial row crosses to the host to read one entry
+        return Answer(q, float(_fetch(tr, d)[q.target]), "target")
 
     def _solve_batch(self, handle, queries: list) -> list:
         """One bucket-padded multisource solve answering ``queries``
@@ -798,10 +819,14 @@ class MicroBatchScheduler:
                 self._probe("solve", handle.name)
                 ms = self._sweep_cap(handle.name)
                 t0 = time.perf_counter() if obs else 0.0
-                D, sw, e, conv = sssp_multisource_csr_sharded(
-                    parts, jnp.asarray(padded, jnp.int32), choice.mesh,
-                    axis=choice.axis, ops=pops, max_sweeps=ms)
-                rows = np.asarray(D)[:, :handle.n]
+                with tr.span("launch"):
+                    D, sw, e, conv = sssp_multisource_csr_sharded(
+                        parts, jnp.asarray(padded, jnp.int32), choice.mesh,
+                        axis=choice.axis, ops=pops, max_sweeps=ms)
+                with tr.span("wait"):
+                    jax.block_until_ready((D, sw, e, conv))
+                D, sw, e, conv = _fetch(tr, (D, sw, e, conv))
+                rows = D[:, :handle.n]
                 converged = bool(int(conv))
                 edges = int(e)
                 self._c["sharded_batches"].inc()
@@ -816,11 +841,14 @@ class MicroBatchScheduler:
                 self._probe("solve", handle.name)
                 ms = self._sweep_cap(handle.name)
                 t0 = time.perf_counter() if obs else 0.0
-                D, sw, conv = sssp_multisource_csr(
-                    ops, jnp.asarray(padded, jnp.int32),
-                    n=handle.n, sweep_fn=handle.multisource_sweep_fn(),
-                    max_sweeps=ms)
-                rows = np.asarray(D)
+                with tr.span("launch"):
+                    D, sw, conv = sssp_multisource_csr(
+                        ops, jnp.asarray(padded, jnp.int32),
+                        n=handle.n, sweep_fn=handle.multisource_sweep_fn(),
+                        max_sweeps=ms)
+                with tr.span("wait"):
+                    jax.block_until_ready((D, sw, conv))
+                rows, sw, conv = _fetch(tr, (D, sw, conv))
                 converged = bool(conv)
                 # the segment engine relaxes every stored arc for every
                 # bucket lane each sweep — exact, not sampled
