@@ -13,10 +13,17 @@ shape static so the whole loop stays inside one jit:
    with the sentinel id n) and an exclusive cumsum of out-degrees — the
    classic stream-compaction step of GPU frontier BFS/SSSP.
 2. **Gather** only the frontier vertices' out-edge windows from the
-   outgoing CSR view (``CsrGraph.out_csr()``), a chunk of edge slots at a
-   time: the *number of chunks* ``ceil(E / chunk)`` is a traced value of an
-   inner ``lax.while_loop``, so per-sweep work tracks the actual frontier
-   edge count E (rounded up to one chunk) instead of m.
+   outgoing CSR view (``CsrGraph.out_csr()``), up to a chunk of edge slots
+   at a time in an inner ``lax.while_loop`` whose trip count is traced, so
+   per-sweep work tracks the actual frontier edge count E (rounded up to
+   one chunk) instead of m.  The rows a chunk touches are one contiguous
+   run of the compaction, so the loop carries a row cursor and finds each
+   slot's row by comparing it with one ``chunk + 1`` slice of the window
+   offsets taken at the cursor, with no per-slot search over the whole
+   frontier.  A run of rows with no slots can cut a step short, and the
+   cursor still moves a chunk of rows on: ``ceil(E / chunk)`` steps when
+   every row has a slot.  Each slot still meets the same row and arc, so
+   the candidates, and the fixpoint, are bitwise unchanged.
 3. **Scatter-min** the candidates ``dist[u] + w`` into the new distance
    vector with ``.at[dst].min`` — the TPU-legal replacement for the CUDA
    kernel's ``atomicMin``, associative and deterministic.
@@ -111,39 +118,68 @@ def frontier_operands(cg, *, with_ell: bool = False,
 def _slot_minloop(nd, starts, off, E, m, F, *, chunk: int, emit,
                   scatter=None):
     """Chunked slot walker shared by the push and pull relax forms: walk
-    ``E`` edge slots ``chunk`` at a time in a ``lax.while_loop`` (trip
-    count tracks the actual slot count, the stream-compaction core of
-    the frontier engines), map each slot to its owning compacted row —
-    ``searchsorted(off, slot, 'right') - 1`` picks the last row whose
-    window starts at or before the slot, landing past zero-degree ties —
-    and its in-window position, then scatter-min whatever ``emit(row,
-    pos, valid) -> (cand, tgt)`` produces (invalid slots must emit INF
-    aimed at a drop id; scatter mode="drop").  ``scatter`` overrides the
-    per-slot scatter-min for callers whose state isn't a flat (n,) row —
-    the multisource form scatter-mins a (S, chunk) candidate block into
-    distance-matrix columns."""
+    ``E`` edge slots at most ``chunk`` at a time in a ``lax.while_loop``
+    (trip count tracks the actual slot count, the stream-compaction core
+    of the frontier engines), map each slot to its owning compacted row —
+    the last row whose window starts at or before the slot, landing past
+    zero-degree ties — and its in-window position, then scatter-min
+    whatever ``emit(row, pos, valid) -> (cand, tgt)`` produces (invalid
+    slots must emit INF aimed at a drop id; scatter mode="drop").
+    ``scatter`` overrides the per-slot scatter-min for callers whose state
+    isn't a flat (n,) row — the multisource form scatter-mins a (S, chunk)
+    candidate block into distance-matrix columns.
+
+    The rows a step's slots belong to are one contiguous run of the
+    compaction, so the loop carries a **row cursor** ``r0`` (at or before
+    the owner of the step's first slot ``b``) and reads one contiguous
+    ``chunk + 1`` slice of ``off`` from it; ``off`` is padded once per
+    walk past its end with the dtype's maximum, so the slice never
+    clamps.  A slot's row is ``r0 + #(slice <= slot) - 1``, a dense
+    compare-and-sum with no gather.  The step's slots stop at
+    ``min(b + chunk, off[r0 + chunk], E)`` (never below ``b``), the
+    ``+1`` entry bounding the slots whose owner lies inside the slice.
+    When every row has a slot that bound never bites; a run of zero-slot
+    rows makes a short (even empty) step, which still moves the cursor
+    ``chunk`` rows on, so the walk takes at most ``ceil(E / chunk) +
+    ceil(Z / chunk)`` steps with Z zero-slot rows.  Every slot in
+    ``[0, E)`` is emitted exactly once with the same row and position as a
+    whole-array search would give, and scatter-min is exact and
+    order-free, so the result is bitwise the same however the slots fall
+    into steps.
+
+    Returns ``(nd, steps)``, the walk's step count."""
     if scatter is None:
         def scatter(nd2, tgt, cand):
             return nd2.at[tgt].min(cand, mode="drop")
 
+    lane = jnp.arange(chunk, dtype=off.dtype)
+    offp = jnp.concatenate(
+        [off, jnp.full((chunk + 1,), jnp.iinfo(off.dtype).max, off.dtype)])
+
     def cond(carry):
-        _, c = carry
-        return c * chunk < E
+        _, b, _, _ = carry
+        return b < E
 
     def body(carry):
-        nd2, c = carry
-        slots = c * chunk + jnp.arange(chunk, dtype=jnp.int32)
-        valid = slots < E
-        row = jnp.searchsorted(off, slots, side="right") - 1
+        nd2, b, r0, steps = carry
+        win = lax.dynamic_slice_in_dim(offp, r0, chunk + 1)
+        limit = jnp.clip(jnp.minimum(b + chunk, win[-1]), b, E)
+        slots = b + lane
+        valid = slots < limit
+        row = r0 + jnp.sum(win[:, None] <= slots[None, :], axis=0,
+                           dtype=r0.dtype) - 1
         row = jnp.clip(row, 0, F - 1)
         pos = starts[row] + (slots - off[row])
         pos = jnp.clip(pos, 0, m - 1)
         cand, tgt = emit(row, pos, valid)
-        return scatter(nd2, tgt, cand), c + 1
+        r0 = r0 + jnp.sum(win <= limit, dtype=r0.dtype) - 1
+        return scatter(nd2, tgt, cand), limit, r0, steps + 1
 
+    zero = jnp.zeros_like(E, off.dtype)     # varies wherever E does
     with jax.named_scope("frontier.relax"):
-        nd, _ = lax.while_loop(cond, body, (nd, jnp.int32(0)))
-    return nd
+        nd, _, _, steps = lax.while_loop(
+            cond, body, (nd, zero, zero, jnp.int32(0)))
+    return nd, steps
 
 
 def relax_edge_slots(nd, row_dist, starts, off, E, out_dst, out_w, *,
@@ -173,7 +209,7 @@ def relax_edge_slots(nd, row_dist, starts, off, E, out_dst, out_w, *,
         return cand, tgt
 
     return _slot_minloop(nd, starts, off, E, m, row_dist.shape[0],
-                         chunk=chunk, emit=emit)
+                         chunk=chunk, emit=emit)[0]
 
 
 def relax_edge_slots_multi(ND, row_D, starts, off, E, out_dst, out_w, *,
@@ -206,7 +242,7 @@ def relax_edge_slots_multi(ND, row_D, starts, off, E, out_dst, out_w, *,
         return nd2.at[:, tgt].min(cand, mode="drop")
 
     return _slot_minloop(ND, starts, off, E, m, row_D.shape[1],
-                         chunk=chunk, emit=emit, scatter=scatter)
+                         chunk=chunk, emit=emit, scatter=scatter)[0]
 
 
 def pull_edge_slots(nd, fids, src_dist, starts, off, E, in_src, in_w, *,
@@ -235,7 +271,7 @@ def pull_edge_slots(nd, fids, src_dist, starts, off, E, in_src, in_w, *,
         return cand, tgt
 
     return _slot_minloop(nd, starts, off, E, m, fids.shape[0],
-                         chunk=chunk, emit=emit)
+                         chunk=chunk, emit=emit)[0]
 
 
 @functools.lru_cache(maxsize=None)

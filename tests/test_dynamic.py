@@ -17,6 +17,7 @@ import pytest
 
 import jax.numpy as jnp
 
+from conftest import dijkstra_oracle
 from repro.core import csr as C
 from repro.core.api import shortest_paths
 from repro.core.bellman_csr import sssp_bellman_csr, sssp_multisource_csr
@@ -212,6 +213,34 @@ def test_pull_edge_slots_matches_naive_reference():
             want[r] = min(want[r],
                           np.float32(dist[src[p]] + w[p]))
     assert np.array_equal(np.asarray(nd), want)
+
+
+def test_zero_base_degree_overlay_sources_in_sink_runs_bitwise():
+    """Vertices with no base out-arc sit in runs longer than chunk=4 of the
+    compaction, and two of them go on only through overlay arcs: the
+    frontier solve and a repair must keep them in the active set and
+    match the heap oracle (integer weights: exact f32 sums) bitwise."""
+    n = 30
+    arcs = [(0, v, 10 + v) for v in range(1, 9)] + [(0, 9, 1)]
+    arcs += [(v, v + 1, 4) for v in range(9, n - 1)]
+    e = np.array([(u, v) for u, v, _ in arcs])
+    w = np.array([x for _, _, x in arcs], np.float64)
+    cg = C.csr_from_edge_list(n, e, w, directed=True)
+    assert np.all(np.diff(cg.out_csr()[0])[1:9] == 0)     # sinks in base
+    dyn = DynamicGraph(cg, compact_threshold=None)
+    prev = solve_dynamic(dyn, 0, chunk=4)
+    dyn.add_edge(3, 20, 1.0)                      # overlay out of a sink
+    dyn.add_edge(6, 27, 2.0)
+    batch = dyn.commit()
+    snap = dyn.snapshot()
+    want = dijkstra_oracle(snap, 0).astype(np.float32)
+    assert want[20] == 14.0 and want[27] == 18.0  # through the overlay
+    res = solve_dynamic(dyn, 0, chunk=4)
+    assert np.array_equal(res.dist, want)
+    assert np.array_equal(res.dist, _serial(dyn, 0).dist)
+    rep, _ = repair_sssp(dyn, prev, batch, chunk=4)
+    assert np.array_equal(rep.dist, want)
+    assert np.array_equal(rep.pred, res.pred)
 
 
 # ---------------------------------------------------------------------------

@@ -6,18 +6,23 @@ the edges-relaxed counter proves the O(frontier out-degree) sweeps do
 strictly less work than bellman_csr's O(m) sweeps where frontiers are
 narrow, and the batched CSR engine equals S independent solves.
 """
+import math
+
 import numpy as np
 import pytest
 
 import jax.numpy as jnp
+from jax import lax
 
 from conftest import dijkstra_oracle, finite_close
 from repro.core import csr as C
 from repro.core import graph as G
 from repro.core.api import recover_pred, shortest_paths
 from repro.core.bellman_csr import csr_operands, sssp_multisource_csr
+from repro.core import frontier as FR
 from repro.core.frontier import (frontier_operands, make_flat_sweep_fn,
-                                 sssp_frontier)
+                                 pull_edge_slots, relax_edge_slots,
+                                 relax_edge_slots_multi, sssp_frontier)
 from repro.kernels.frontier_relax import (frontier_cand_block,
                                           frontier_cand_ref,
                                           frontier_relax_ref)
@@ -291,3 +296,173 @@ def test_frontier_relax_ref_matches_engine_first_sweep():
                               ops["out_ell_w"])
     d1, _, _, _, _ = sssp_frontier(ops, jnp.int32(0), n=n, max_sweeps=1)
     assert np.array_equal(np.asarray(want), np.asarray(d1))
+
+
+# ---------------------------------------------------------------------------
+# the edge-slot walker: row cursor against a whole-array binary search
+# ---------------------------------------------------------------------------
+
+def _search_slot_minloop(nd, starts, off, E, m, F, *, chunk, emit,
+                         scatter=None):
+    """Reference walker: each slot's row by a binary search over all F
+    compacted rows, a full chunk of slots every step.  Same signature and
+    return shape as ``frontier._slot_minloop`` (the step count is None)."""
+    if scatter is None:
+        def scatter(nd2, tgt, cand):
+            return nd2.at[tgt].min(cand, mode="drop")
+
+    def body(carry):
+        nd2, c = carry
+        slots = c * chunk + jnp.arange(chunk, dtype=jnp.int32)
+        valid = slots < E
+        row = jnp.clip(jnp.searchsorted(off, slots, side="right") - 1,
+                       0, F - 1)
+        pos = jnp.clip(starts[row] + (slots - off[row]), 0, m - 1)
+        cand, tgt = emit(row, pos, valid)
+        return scatter(nd2, tgt, cand), c + 1
+
+    nd, _ = lax.while_loop(lambda c: c[1] * chunk < E, body,
+                           (nd, jnp.int32(0)))
+    return nd, None
+
+
+_LAYOUTS = ("no-zeros", "scattered-zeros", "zero-runs", "exact-multiple",
+            "empty")
+
+
+def _windows(layout, chunk, seed, *, n=64, m=300):
+    """Random compacted frontier windows as the compaction lays them out:
+    per-row window starts into an m-arc edge array, the exclusive cumsum of
+    the window lengths, and a tail of sentinel rows with no slots.
+    ``zero-runs`` holds runs of zero-slot rows longer than ``chunk``;
+    ``exact-multiple`` makes E a multiple of ``chunk``; ``empty`` has
+    E = 0.  Returns (starts, off, E, fids)."""
+    rng = np.random.default_rng(seed)
+    rows = max(120, 3 * chunk + 10)
+    degs = rng.integers(1, 6, rows)
+    if layout in ("scattered-zeros", "exact-multiple"):
+        degs[rng.uniform(size=rows) < 0.3] = 0
+    elif layout == "zero-runs":
+        run = chunk + 3
+        for at in (0, rows // 2):                 # at the head, and inside
+            degs[at:at + run] = 0
+    elif layout == "empty":
+        degs[:] = 0
+    if layout == "exact-multiple":
+        need = -int(degs.sum()) % chunk           # one more slot per row
+        degs[np.flatnonzero(degs)[:need]] += 1
+    starts = np.array([rng.integers(0, m - d + 1) for d in degs])
+    tail = rows // 4                              # sentinel rows: no slots
+    degs = np.concatenate([degs, np.zeros(tail, np.int64)])
+    starts = np.concatenate([starts, np.full(tail, m)])
+    fids = np.concatenate([rng.integers(0, n, rows), np.full(tail, n)])
+    off = np.cumsum(degs) - degs
+    E = int(degs.sum())
+    if layout == "exact-multiple":
+        assert E % chunk == 0 and E > 0
+    return (jnp.asarray(starts, jnp.int32), jnp.asarray(off, jnp.int32),
+            jnp.int32(E), jnp.asarray(fids, jnp.int32))
+
+
+def _relax_form(form, chunk, starts, off, E, fids, seed, *, n=64, m=300):
+    """One call of the public relax form ``form`` on random distances and
+    arcs (non-integer, so a slot given the wrong row changes a minimum)."""
+    rng = np.random.default_rng(seed + 1)
+    F = fids.shape[0]
+    nd = jnp.asarray(rng.uniform(20, 60, n), jnp.float32)
+    dst = jnp.asarray(rng.integers(0, n, m), jnp.int32)
+    w = jnp.asarray(rng.uniform(0.5, 10, m), jnp.float32)
+    rd = rng.uniform(0, 40, (3, F)).astype(np.float32)
+    rd[rng.uniform(size=rd.shape) < 0.2] = np.inf
+    drop = jnp.int32(n)
+    if form == "push":
+        return relax_edge_slots(nd, jnp.asarray(rd[0]), starts, off, E, dst,
+                                w, chunk=chunk, drop_id=drop)
+    if form == "multi":
+        ND = jnp.tile(nd, (3, 1)) + jnp.arange(3, dtype=jnp.float32)[:, None]
+        return relax_edge_slots_multi(ND, jnp.asarray(rd), starts, off, E,
+                                      dst, w, chunk=chunk, drop_id=drop)
+    src_dist = jnp.asarray(rd[0, :n])
+    return pull_edge_slots(nd, fids, src_dist, starts, off, E, dst, w,
+                           chunk=chunk, drop_id=drop)
+
+
+@pytest.mark.parametrize("layout", _LAYOUTS)
+@pytest.mark.parametrize("chunk", [1, 4, 1024])
+@pytest.mark.parametrize("form", ["push", "multi", "pull"])
+def test_slot_walker_bitwise_matches_search_walker(form, chunk, layout,
+                                                   monkeypatch):
+    """Every relax form through the row-cursor walker equals the same form
+    through the whole-array search walker, bit for bit."""
+    seed = 7 * chunk + _LAYOUTS.index(layout)
+    starts, off, E, fids = _windows(layout, chunk, seed)
+    got = _relax_form(form, chunk, starts, off, E, fids, seed)
+    monkeypatch.setattr(FR, "_slot_minloop", _search_slot_minloop)
+    want = _relax_form(form, chunk, starts, off, E, fids, seed)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    if layout != "empty":           # the candidates did land somewhere
+        assert not np.array_equal(
+            np.asarray(got),
+            np.asarray(_relax_form(form, chunk, starts, off, jnp.int32(0),
+                                   fids, seed)))
+
+
+@pytest.mark.parametrize("layout", _LAYOUTS)
+@pytest.mark.parametrize("chunk", [1, 4, 1024])
+def test_slot_walker_step_count(chunk, layout):
+    """ceil(E / chunk) steps when every row before the sentinel tail has a
+    slot; at most ceil(E / chunk) + ceil(Z / chunk) with Z zero-slot rows
+    (the tail included)."""
+    starts, off, E, _ = _windows(layout, chunk, 3 + chunk)
+    F = int(off.shape[0])
+    degs = np.diff(np.append(np.asarray(off), int(E)))
+    Z = int(np.sum(degs == 0))
+
+    def emit(row, pos, valid):
+        return (jnp.full(valid.shape, jnp.inf),
+                jnp.full(valid.shape, 8, jnp.int32))
+
+    _, steps = FR._slot_minloop(jnp.zeros(8), starts, off, E, 300, F,
+                                chunk=chunk, emit=emit)
+    full = math.ceil(int(E) / chunk)
+    if layout == "no-zeros":
+        assert int(steps) == full
+    else:
+        assert full <= int(steps) <= full + math.ceil(Z / chunk)
+
+
+def _sink_fan(n=40):
+    """Directed, integer weights: the source fans out to a run of 12 sinks
+    (ids 1..12, no out-arcs) before the one vertex that goes on, which
+    fans out to another run of sinks and a path with arcs back into
+    them — every sweep's compaction holds a run of zero-slot rows."""
+    arcs = [(0, v, 20 + v) for v in range(1, 13)] + [(0, 13, 1)]
+    arcs += [(13, v, v) for v in range(14, 21)] + [(13, 21, 2)]
+    arcs += [(v, v + 1, 3) for v in range(21, n - 1)]
+    arcs += [(v, (v % 19) + 1, 1) for v in range(22, n, 3)]
+    e = np.array([(u, v) for u, v, _ in arcs])
+    w = np.array([x for _, _, x in arcs], np.float64)
+    return C.csr_from_edge_list(n, e, w, directed=True)
+
+
+def test_frontier_sink_run_longer_than_chunk_matches_oracle(monkeypatch):
+    """chunk=4 against a compaction holding runs of more than 4 sinks: the
+    walk's short steps give the heap oracle's distances exactly (integer
+    weights, so f32 sums are exact) and the search walker's sweeps,
+    edges_relaxed and converged."""
+    cg = _sink_fan()
+    ops = frontier_operands(cg)
+    d, p, s, e, c = sssp_frontier(ops, jnp.int32(0), n=cg.n, chunk=4)
+    assert np.array_equal(np.asarray(d),
+                          dijkstra_oracle(cg, 0).astype(np.float32))
+    assert np.array_equal(np.asarray(d),
+                          shortest_paths(cg, 0, engine="serial").dist)
+    base = make_flat_sweep_fn(4)
+    monkeypatch.setattr(FR, "_slot_minloop", _search_slot_minloop)
+
+    def search_sweep(*args):                     # a fresh jit static
+        return base(*args)
+
+    want = sssp_frontier(ops, jnp.int32(0), n=cg.n, sweep_fn=search_sweep)
+    for x, y in zip((d, p, s, e, c), want):
+        assert np.array_equal(np.asarray(x), np.asarray(y))
