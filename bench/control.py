@@ -23,10 +23,11 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def control_reading(cell, seed: int, jobs: int) -> dict:
+def control_reading(cell, seed: int, jobs: int, root: str) -> dict:
     from bench import graphs, harness, traffic
 
-    csrs = [graphs.build(cell.config, seed, g)
+    gen = graphs.generator(cell.config["generator"], root)
+    csrs = [graphs.build(cell.config, seed, g, gen)
             for g in range(cell.mix["graphs"])]
     _, drawn = traffic.make_jobs(cell.mix, csrs, seed)
     queries = [(job.graph, s, t) for i in range(jobs)
@@ -34,12 +35,12 @@ def control_reading(cell, seed: int, jobs: int) -> dict:
     picked = harness.picked_sources(queries, seed,
                                     cell.mix["check_sources"])
     queries = [q for q in queries if q[:2] in set(picked)]
-    rows = harness.reference_rows(csrs, cell.config, seed, picked,
+    rows = harness.reference_rows(csrs, cell.config, seed, picked, root,
                                   precision="bf16")
     got = [rows[g, s] if t is None else rows[g, s][t]
            for g, s, t in queries]
     wrong, compared = harness.check(csrs, cell.config, seed, queries, got,
-                                    picked)
+                                    picked, root)
     return {"workload": cell.name, "seed": seed, "compared": compared,
             "wrong_answers": wrong, "limit": 0}
 
@@ -55,7 +56,8 @@ def main(argv=None) -> int:
 
     cell = harness.load_cell(args.workload, ROOT)
     for seed in args.seeds:
-        print(json.dumps(control_reading(cell, seed, args.jobs)), flush=True)
+        print(json.dumps(control_reading(cell, seed, args.jobs, ROOT)),
+              flush=True)
     return 0
 
 

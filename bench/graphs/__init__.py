@@ -7,10 +7,17 @@ A graph is returned as incoming-arc CSR arrays: row ``v`` of ``indptr`` /
 layout the program's ``CsrGraph`` takes.  Undirected edges are stored in
 both directions; self-loops are dropped and parallel edges keep the least
 weight.
+
+Each generator is a module of its own, ``bench/graphs/<generator>.py``,
+found by the name in a configuration's ``generator`` key, so a new one is
+a new file.
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
+import os
+import re
 
 import numpy as np
 
@@ -62,32 +69,6 @@ def csr_from_edge_list(n: int, edges: np.ndarray, weights: np.ndarray,
     return Csr(indptr, src, wmin, n)
 
 
-def random_connected(n: int, m: int, *, rng: np.random.Generator,
-                     max_weight: float) -> Csr:
-    """The paper's Table II corpus: ``m`` distinct undirected edges, a
-    random spanning path (so the graph is connected) and uniform random
-    pairs, with weights uniform(1, max_weight).  No self-loops and no
-    parallel edges, so every seed gives exactly ``2 m`` arcs: the same
-    shapes, the same compiled programs."""
-    if not n - 1 <= m <= n * (n - 1) // 2:
-        raise ValueError(f"{m} edges cannot connect {n} vertices simply")
-    perm = rng.permutation(n)
-    u, v = perm[:-1], perm[1:]
-    keys = np.minimum(u, v) * np.int64(n) + np.maximum(u, v)
-    while keys.size < m:
-        need = m - keys.size
-        a = rng.integers(0, n, size=2 * need + 16)
-        b = rng.integers(0, n, size=2 * need + 16)
-        new = (np.minimum(a, b) * np.int64(n) + np.maximum(a, b))[a != b]
-        _, first = np.unique(new, return_index=True)
-        new = new[np.sort(first)]               # first draw of each pair
-        new = new[~np.isin(new, keys)][:need]
-        keys = np.concatenate([keys, new])
-    e = np.stack([keys // n, keys % n], axis=1)
-    w = rng.uniform(1.0, max_weight, size=m)
-    return csr_from_edge_list(n, e, w)
-
-
 def stream(kind: int, seed: int, index: int = 0) -> np.random.Generator:
     """The generator of a run's ``seed`` for one kind of input and one of
     its graphs (graph 0 keeps the two-word key of a one-graph run)."""
@@ -95,12 +76,29 @@ def stream(kind: int, seed: int, index: int = 0) -> np.random.Generator:
     return np.random.default_rng(key)
 
 
-def build(config: dict, seed: int, index: int = 0) -> Csr:
+def generator(name: str, root: str):
+    """The generator module ``bench/graphs/<name>.py`` of the checkout at
+    ``root``, found by the name a configuration's ``generator`` gives.
+    It has ``build(config, rng) -> Csr`` and ``tiny(config) -> dict``,
+    the keys that cut the configuration to a size the CPU tests run in
+    about a second."""
+    where = os.path.join(root, "bench", "graphs")
+    path = os.path.join(where, f"{name}.py")
+    if os.path.basename(name) != name or not os.path.isfile(path):
+        present = sorted(f[:-3] for f in os.listdir(where)
+                         if f.endswith(".py") and not f.startswith("_"))
+        raise ValueError(f"unknown generator {name!r}; the generator files "
+                         f"in {where} are {present}")
+    spec = importlib.util.spec_from_file_location(
+        "bench_graph_" + re.sub(r"\W", "_", name), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def build(config: dict, seed: int, index: int, gen) -> Csr:
     """Graph ``index`` of a run: the graph a configuration file describes,
-    drawn from the run's ``seed``.  Every seed and index gives the
-    configuration's sizes, and another graph of them."""
-    rng = stream(GRAPH_STREAM, seed, index)
-    if config["generator"] == "random_connected":
-        return random_connected(config["n"], config["edges"], rng=rng,
-                                max_weight=config["max_weight"])
-    raise ValueError(f"unknown generator {config['generator']!r}")
+    drawn from the run's ``seed`` by ``gen``, the generator module it
+    names.  Every seed and index gives another graph of the
+    configuration."""
+    return gen.build(config, stream(GRAPH_STREAM, seed, index))

@@ -1,8 +1,9 @@
 """The benchmark harness: one cell, one seed, one window.
 
-A cell names a configuration (``bench/configs/<config>.json``) and a
-traffic mix (``bench/traffic/<mix>.json``); every metric, end-to-end and
-per-layer, is a reader of its own in ``bench/metrics/<metric>.py``.  All
+A cell names a configuration (``bench/configs/<config>.json``, whose
+``generator`` names ``bench/graphs/<generator>.py``) and a traffic mix
+(``bench/traffic/<mix>.json``); every metric, end-to-end and per-layer,
+is a reader of its own in ``bench/metrics/<metric>.py``.  All
 are found by the names in ``BENCHMARK.json``, so a new cell or metric is a
 new file, not an edit here.
 
@@ -203,27 +204,30 @@ CHECK_STREAM = 2        # a run's seed picks the compared sources from here
 
 
 def _pool_map(fn, args: list, config: dict, seed: int, count: int,
-              precision: str):
+              precision: str, root: str):
     import multiprocessing
 
     workers = max(1, min(len(args), (os.cpu_count() or 2) - 1))
     ctx = multiprocessing.get_context("spawn")
     with ProcessPoolExecutor(workers, mp_context=ctx,
                              initializer=reference.init_worker,
-                             initargs=(config, seed, count, precision)) as ex:
+                             initargs=(config, seed, count, precision,
+                                       root)) as ex:
         return list(ex.map(fn, args))
 
 
 def reference_rows(csrs: list, config: dict, seed: int, picked: list,
-                   precision: str = "f32") -> dict:
-    """The reference's full row from each picked ``(graph, source)``."""
+                   root: str, precision: str = "f32") -> dict:
+    """The reference's full row from each picked ``(graph, source)``;
+    worker processes rebuild the graphs with the generator of the
+    checkout at ``root``."""
     if len(picked) * csrs[0].arcs < POOL_MIN_WORK:
         dij = {g: reference.Dijkstra(csrs[g], precision)
                for g in sorted({g for g, _ in picked})}
         rows = [dij[g].solve(s) for g, s in picked]
     else:
         rows = _pool_map(reference.worker_solve, list(picked), config, seed,
-                         len(csrs), precision)
+                         len(csrs), precision, root)
     return dict(zip(picked, rows))
 
 
@@ -249,11 +253,11 @@ def picked_sources(queries: list, seed: int, count: int) -> list:
 
 
 def check(csrs: list, config: dict, seed: int, queries: list, values: list,
-          picked: list) -> tuple:
+          picked: list, root: str) -> tuple:
     """``(wrong, compared)``.  Every answer of a picked source is compared
     with the reference's row from it; an answer of another source counts
     wrong only where it is missing or an error."""
-    rows = reference_rows(csrs, config, seed, picked)
+    rows = reference_rows(csrs, config, seed, picked, root)
     wrong = compared = 0
     for (g, s, t), got in zip(queries, values, strict=True):
         row = rows.get((g, s))
@@ -318,7 +322,8 @@ def run_cell(jax, cell: Cell, seed: int, seconds: float, trace: bool, *,
     config, mix = cell.config, cell.mix
     kind = mix["job"]["kind"]
     phases = [("start", time.perf_counter() - t0)]
-    csrs = [graphs.build(config, seed, g) for g in range(mix["graphs"])]
+    gen = graphs.generator(config["generator"], root)
+    csrs = [graphs.build(config, seed, g, gen) for g in range(mix["graphs"])]
     phases.append(("graphs", time.perf_counter() - t0))
     warm, jobs = traffic.make_jobs(mix, csrs, seed)
     phases.append(("jobs", time.perf_counter() - t0))
@@ -364,7 +369,8 @@ def run_cell(jax, cell: Cell, seed: int, seconds: float, trace: bool, *,
 
     queries, values = served_values(window, kind)
     picked = picked_sources(queries, seed, mix["check_sources"])
-    wrong, compared = check(csrs, config, seed, queries, values, picked)
+    wrong, compared = check(csrs, config, seed, queries, values, picked,
+                            root)
 
     ctx = {"cell": cell.name, "kind": kind, "setup_s": setup_s,
            "window": window, "spans": spans, "trace": reduced,

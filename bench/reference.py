@@ -94,13 +94,14 @@ class Dijkstra:
 _WORKER: dict = {}
 
 
-def init_worker(config: dict, seed: int, count: int,
-                precision: str) -> None:
+def init_worker(config: dict, seed: int, count: int, precision: str,
+                root: str) -> None:
     """Rebuild a run's ``count`` graphs from its configuration and seed in
-    a worker."""
-    from bench.graphs import build
+    a worker, with the generator of the checkout at ``root``."""
+    from bench.graphs import build, generator
 
-    _WORKER["dij"] = [Dijkstra(build(config, seed, g), precision)
+    gen = generator(config["generator"], root)
+    _WORKER["dij"] = [Dijkstra(build(config, seed, g, gen), precision)
                       for g in range(count)]
 
 

@@ -1,14 +1,19 @@
 """The check that decides ``correct``: the program's answers pass it, and
 the control and each fault that a cell can have fail it.  The runs here
 skip the look for a chip and drive the rest of a run at a small size."""
+import json
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from bench import control, harness
+from bench.tests.conftest import ROOT
 
-CELLS = ["tableii-40k.p2p", "tableii-40k.batch"]
+with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+    CELLS = [w["name"] for w in json.load(f)["workloads"]]
 
 
 def run(root, cell, seed=2 ** 31 + 5):
@@ -29,7 +34,7 @@ def test_the_program_passes(tiny_root, cell):
 @pytest.mark.parametrize("cell", CELLS)
 def test_the_bf16_control_fails(tiny_root, cell):
     reading = control.control_reading(harness.load_cell(cell, tiny_root),
-                                      2 ** 31 + 5, jobs=8)
+                                      2 ** 31 + 5, 8, tiny_root)
     assert reading["wrong_answers"] > 0 and reading["limit"] == 0
 
 

@@ -5,6 +5,7 @@ import pytest
 from scipy.sparse.csgraph import dijkstra
 
 from bench import graphs, reference, traffic
+from bench.tests.conftest import build
 from bench.tests.test_inputs import RANDOM
 
 
@@ -23,7 +24,7 @@ def test_integer_weights_give_scipy_exactly():
 def test_labels_are_the_f32_fixpoint(seed):
     """No arc improves a label in f32, and every label but the source's
     is attained by an arc: the certificate of the least f32 fixpoint."""
-    g = graphs.build(RANDOM, seed)
+    g = build(RANDOM, seed)
     d = reference.Dijkstra(g).solve(7)
     dst = np.repeat(np.arange(g.n), np.diff(g.indptr))
     via = d[g.indices] + g.weights
@@ -35,7 +36,7 @@ def test_labels_are_the_f32_fixpoint(seed):
 
 
 def test_early_exit_returns_the_full_solve_label():
-    g = graphs.build(RANDOM, 2)
+    g = build(RANDOM, 2)
     dij = reference.Dijkstra(g)
     row = dij.solve(3)
     for t in (0, 3, 17, 499):
@@ -43,7 +44,7 @@ def test_early_exit_returns_the_full_solve_label():
 
 
 def test_bf16_control_differs_from_f32():
-    g = graphs.build(RANDOM, 3)
+    g = build(RANDOM, 3)
     f32 = reference.Dijkstra(g).solve(0)
     bf16 = reference.Dijkstra(g, "bf16").solve(0)
     assert np.count_nonzero(f32 != bf16) > g.n // 2

@@ -270,7 +270,8 @@ def test_fetch_readers_on_the_served_path(tiny_root):
     from repro.serve import DistanceCache, GraphRegistry, MicroBatchScheduler
 
     cell = harness.load_cell("tableii-40k.p2p", tiny_root)
-    csr = graphs.build(cell.config, 5, 0)
+    csr = graphs.build(cell.config, 5, 0,
+                       graphs.generator(cell.config["generator"], tiny_root))
     warm, jobs = traffic.make_jobs(cell.mix, [csr], 5)
     registry = GraphRegistry()
     sched = MicroBatchScheduler(registry, DistanceCache(capacity=8))

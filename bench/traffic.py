@@ -17,8 +17,10 @@ the next.
     the sweeps of every job on it, so more graphs a run average that out.
 ``sources``
     How many distinct sources a run draws on each graph, uniform over
-    its vertices.  Each graph's warm-up job takes others.  When the
-    window answers every job it starts the list again.
+    its vertices with an arc out, as Graph500 draws its search keys among
+    the vertices of degree 1 or more (on a connected graph, every
+    vertex).  Each graph's warm-up job takes others.  When the window
+    answers every job it starts the list again.
 ``check_sources``
     How many of the sources the window answered have their answers
     compared with the reference (all of them where fewer).
@@ -112,10 +114,13 @@ def _graph_jobs(mix: dict, csr, rng: np.random.Generator):
         raise ValueError(f"unknown job kind {job['kind']!r}")
     if count % per_job:
         raise ValueError(f"{count} sources do not fill jobs of {per_job}")
-    if count + per_job > csr.n:
+    # the vertices with an arc out (``indices`` holds each arc's source)
+    pool = np.flatnonzero(np.bincount(csr.indices, minlength=csr.n))
+    if count + per_job > pool.size:
         raise ValueError(f"{count + per_job} distinct sources asked of "
-                         f"{csr.n} vertices")
-    sources = rng.choice(csr.n, size=count + per_job, replace=False)
+                         f"{pool.size} vertices with an arc out")
+    sources = pool[rng.choice(pool.size, size=count + per_job,
+                              replace=False)]
     warm_src, sources = sources[:per_job], sources[per_job:]
     if job["kind"] == "p2p":
         out = _scipy_out(csr)
