@@ -175,7 +175,9 @@ def check_batch_fits(jax, cg, bucket: int) -> None:
 
     arc = lambda dt: jax.ShapeDtypeStruct((cg.nnz,), dt)   # noqa: E731
     ops = {"src": arc(jnp.int32), "dst": arc(jnp.int32),
-           "w": arc(jnp.float32)}
+           "w": arc(jnp.float32), "rank": arc(jnp.int32),
+           "last": jax.ShapeDtypeStruct((cg.n,), jnp.int32),
+           "scan_steps": jax.ShapeDtypeStruct((), jnp.int32)}
     ma = sssp_multisource_csr.lower(
         ops, jax.ShapeDtypeStruct((bucket,), jnp.int32),
         n=cg.n).compile().memory_analysis()

@@ -22,7 +22,8 @@ Engines (paper §III):
     delta_stepping_kernel
                        same, fused Pallas bucket-relax kernel
                        (kernels/bucket_relax)
-    multisource_csr    batched (S, n) fixpoint on CSR edges      (beyond-paper)
+    multisource_csr    batched (S, n) fixpoint on CSR edges, segmented
+                       min-scan sweep, no scatter            (beyond-paper)
     bellman_csr_sharded vertex-partitioned CSR fixpoint: O(m/P) local
                        segment-min + 1 all-gather/sweep (beyond-paper,
                        core/sharded_csr.py; needs a mesh)
@@ -200,6 +201,8 @@ class SsspResult:
     # such a result as exact (serve/errors.NotConverged is the serving
     # consumer).  None for engines without the flag (serial, dense).
     converged: Optional[bool] = None
+    # doubling steps of each sweep's segmented min-scan (multisource_csr)
+    scan_steps: int = 0
 
 
 def _edge_count(g) -> int:
@@ -289,7 +292,8 @@ def shortest_paths(
         edges = 0 if res.edges_relaxed is None else int(res.edges_relaxed)
         conv = True if res.converged is None else bool(res.converged)
         sp.set(engine=res.engine, n=n, m=m, batch=batch, sweeps=sweeps,
-               edges_relaxed=edges, converged=conv)
+               scan_steps=res.scan_steps, edges_relaxed=edges,
+               converged=conv)
     nprocs = (int(mesh.devices.size)
               if mesh is not None and res.engine in SHARDED_CSR_ENGINES
               else 1)
@@ -304,8 +308,8 @@ def shortest_paths(
     else:
         dval = 0.0
     cl.emit(engine=res.engine, n=n, m=m, batch=batch, nprocs=nprocs,
-            delta=dval, sweeps=sweeps, edges_relaxed=edges,
-            wall_ms=wall_ms, converged=conv)
+            delta=dval, sweeps=sweeps, scan_steps=res.scan_steps,
+            edges_relaxed=edges, wall_ms=wall_ms, converged=conv)
     return res
 
 
@@ -520,7 +524,8 @@ def _shortest_paths(
         )
         return SsspResult(np.asarray(D), None, int(s), engine,
                           edges_relaxed=int(s) * cg.nnz * srcs.shape[0],
-                          sources=np.asarray(srcs), converged=bool(c))
+                          sources=np.asarray(srcs), converged=bool(c),
+                          scan_steps=cg.in_runs()[2])
 
     if engine in CSR_ENGINES:
         if cg is None:

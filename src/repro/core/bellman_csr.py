@@ -25,7 +25,10 @@ Frontier-restricted relaxation lives in core/frontier.py (api engines
 touches only their out-edges, O(frontier out-degree) per sweep instead of
 this engine's O(m).  ``sssp_multisource_csr`` below is the batched twin:
 S sources share one (S, m) gather of the edge arrays per sweep — the
-sparse analogue of core/multisource.py's min-plus matmul.
+sparse analogue of core/multisource.py's min-plus matmul.  Its sweep
+reduces without a scatter: the arcs are sorted by destination, so each
+vertex's candidates are one run of ``via``, and a segmented min-scan by
+doubling plus one gather of each run's last slot gives the same minima.
 """
 from __future__ import annotations
 
@@ -34,6 +37,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from repro.core.multisource import init_dist
@@ -47,15 +51,25 @@ def csr_operands(cg, *, with_ell: bool = False) -> dict:
     engine threads through jit.  ``with_ell`` adds the padded-ELL view the
     Pallas kernel consumes (skipped for the pure segment-min path).
 
+    ``rank``, ``last`` and ``scan_steps`` lay out the destination runs
+    for :func:`segment_relax_sweep_multi` (``CsrGraph.in_runs``); the
+    step count is a device scalar, so graphs of equal n and m share one
+    compiled program whatever their degree histograms.
+
     Deliberately NOT memoized on the CsrGraph (unlike its host-side
     views): caching jax buffers on a long-lived host container would pin
     device memory for the graph's lifetime, and the host numpy views are
     already cached so repeat staging is a plain O(n + m) copy.
     """
+    rank, last, steps = cg.in_runs()
     ops = {
         "src": jnp.asarray(cg.indices),
         "dst": jnp.asarray(cg.dst_ids()),
         "w": jnp.asarray(cg.weights),
+        "rank": jnp.asarray(rank),
+        "last": jnp.asarray(last),
+        # a 0-d ndarray stages without compiling a convert
+        "scan_steps": jnp.asarray(np.asarray(steps, np.int32)),
     }
     if with_ell:
         ell_idx, ell_w = cg.ell()
@@ -135,14 +149,41 @@ def sssp_bellman_csr(
     return dist, pred, sweeps, converged
 
 
+def segmented_min_scan(x: jax.Array, rank: jax.Array,
+                       steps: jax.Array) -> jax.Array:
+    """Inclusive min-scan of each row of ``x`` (S, m) within runs of
+    consecutive slots, ``rank`` (m,) giving each slot's position in its
+    run.  Step j folds in the slot 2^j back where that slot lies in the
+    same run (rank >= 2^j), so after ``steps`` = ceil(log2(longest
+    run)) steps a run's last slot holds the run's minimum.  Each step is
+    one dense shift-and-min over (S, m); the trip count is data, so the
+    shapes never depend on the runs."""
+    def step(j, x):
+        k = jnp.left_shift(jnp.int32(1), j)
+        # the slots that wrap round to the head all have rank < k
+        back = jnp.roll(x, k, axis=1)
+        return jnp.where(rank >= k, jnp.minimum(x, back), x)
+
+    return lax.fori_loop(0, steps, step, x)
+
+
 def segment_relax_sweep_multi(D: jax.Array, csr: dict) -> jax.Array:
     """Batched O(S·m) relax sweep over a (S, n) distance matrix: the sparse
     twin of multisource.relax_sweep_multi_ref.  One gather of the edge
-    index arrays serves all S sources (vmap hoists the shared ``src``/
-    ``dst`` loads), so arithmetic intensity rises S× exactly as in the
-    dense batched engine — per-row results are bitwise identical to S
-    independent ``segment_relax_sweep`` calls by construction."""
-    return jax.vmap(lambda d: segment_relax_sweep(d, csr))(D)
+    arrays serves all S sources, so arithmetic intensity rises S×
+    exactly as in the dense batched engine.  The per-vertex minimum is a
+    segmented min-scan over the destination-sorted candidates and one
+    gather of each vertex's last slot (+inf where it has no arc, the
+    empty segment's identity) — no scatter.  min is exact and
+    order-free, so each row is bitwise the ``segment_relax_sweep`` of
+    that row."""
+    if csr["src"].shape[0] == 0:        # no arcs: nothing to relax
+        return D
+    via = D[:, csr["src"]] + csr["w"]
+    run_min = segmented_min_scan(via, csr["rank"], csr["scan_steps"])
+    cand = jnp.take(run_min, csr["last"], axis=1, mode="fill",
+                    fill_value=INF)
+    return jnp.minimum(D, cand)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "sweep_fn", "max_sweeps"))

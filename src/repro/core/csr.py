@@ -38,6 +38,12 @@ def _freeze(*arrays: np.ndarray):
     return arrays if len(arrays) > 1 else arrays[0]
 
 
+def _row_rank(indptr: np.ndarray) -> np.ndarray:
+    """(nnz,) int64 position of each stored arc within its row."""
+    deg = np.diff(indptr)
+    return np.arange(int(indptr[-1])) - np.repeat(indptr[:-1], deg)
+
+
 def _build_ell(
     indptr: np.ndarray, ids: np.ndarray, weights: np.ndarray,
     n: int, width_multiple: int,
@@ -55,7 +61,7 @@ def _build_ell(
     idx = np.zeros((n, K), np.int32)
     w = np.full((n, K), INF, np.float32)
     rows = np.repeat(np.arange(n), deg)
-    pos = np.arange(int(indptr[-1])) - np.repeat(indptr[:-1], deg)
+    pos = _row_rank(indptr)
     idx[rows, pos] = ids
     w[rows, pos] = weights
     return _freeze(idx, w)
@@ -139,6 +145,22 @@ class CsrGraph:
             deg = np.diff(self.indptr)
             return _freeze(np.repeat(np.arange(self.n, dtype=np.int32), deg))
         return self._memo("_dst_ids", build)
+
+    def in_runs(self) -> tuple[np.ndarray, np.ndarray, int]:
+        """The incoming arcs as one run per destination, for a segmented
+        scan: ``(rank, last, steps)``.  ``rank`` (nnz,) int32 is each
+        arc's position in its run; ``last`` (n,) int32 the index of each
+        vertex's final arc, nnz for a vertex with none; ``steps`` =
+        ceil(log2(max in-degree)), the doubling steps that span the
+        longest run (0 when no run holds two arcs).  Memoized."""
+        def build():
+            deg = np.diff(self.indptr)
+            rank = _row_rank(self.indptr).astype(np.int32)
+            last = np.where(deg > 0, self.indptr[1:] - 1,
+                            self.nnz).astype(np.int32)
+            steps = max(int(deg.max(initial=0)) - 1, 0).bit_length()
+            return (*_freeze(rank, last), steps)
+        return self._memo("_in_runs", build)
 
     def ell(self, width_multiple: int = 8) -> tuple[np.ndarray, np.ndarray]:
         """Padded-ELL view: (n, K) int32 indices and (n, K) float32 weights.

@@ -85,6 +85,7 @@ class CostRecord:
     converged: bool      # fixpoint reached within the sweep cap
     backend: str = ""    # jax.default_backend() at measurement (v2)
     device_kind: str = ""  # device_kind of device 0 at measurement (v2)
+    scan_steps: int = 0  # doubling steps of each sweep's segmented min-scan
 
     def to_dict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)
@@ -114,6 +115,7 @@ class CostLog:
         delta: float = 0.0,
         backend: str = "",
         device_kind: str = "",
+        scan_steps: int = 0,
     ) -> None:
         if not backend or not device_kind:
             # v2: stamp the measuring hardware so fitted models can
@@ -136,6 +138,7 @@ class CostLog:
                 converged=bool(converged),
                 backend=str(backend),
                 device_kind=str(device_kind),
+                scan_steps=int(scan_steps),
             )
         )
 

@@ -90,7 +90,8 @@ def validate_cost_records(rows: List[Dict[str, Any]]) -> List[str]:
                 errs.append(
                     f"record {i}: {key} must be a non-empty string "
                     f"when present (v2)")
-        for key in ("n", "m", "batch", "nprocs", "sweeps", "edges_relaxed"):
+        for key in ("n", "m", "batch", "nprocs", "sweeps", "edges_relaxed",
+                    "scan_steps"):
             if key in r and (not isinstance(r[key], int) or r[key] < 0):
                 errs.append(f"record {i}: {key} must be a non-negative int")
         if "wall_ms" in r and (not isinstance(r["wall_ms"], (int, float)) or r["wall_ms"] < 0):

@@ -8,7 +8,7 @@ worth pinning here:
 * the **staged device operands** — ``csr_operands`` is deliberately not
   memoized on ``CsrGraph`` (core/bellman_csr.py) because a long-lived host
   container shouldn't pin device memory; a registry entry is exactly the
-  long-lived *server* object that should, so both the segment-min and the
+  long-lived *server* object that should, so both the CSR and the
   frontier operand pytrees are staged lazily and cached on the handle;
 * the **landmark set** (serve/landmarks.py), built at registration with
   one batched multisource solve;
@@ -132,9 +132,10 @@ class GraphHandle:
         return (self.name, self.dyn.version, source)
 
     def csr_ops(self) -> dict:
-        """Staged segment-min operands (multisource / bellman_csr path).
-        Dynamic handles resolve to the overlay operand dict, a superset
-        of the static pytree with effective weights."""
+        """Staged CSR operands (multisource / bellman_csr path).
+        Dynamic handles resolve to the overlay operand dict: the arc
+        arrays with effective weights, without the destination-run
+        layout, which :meth:`multisource_sweep_fn`'s sweep never reads."""
         if self.dyn is not None:
             return self.dyn.dyn_ops()
         if self._csr_ops is None:

@@ -811,6 +811,7 @@ class MicroBatchScheduler:
                     sssp_multisource_csr_sharded)
 
                 engine = "multisource_csr_sharded"
+                scan_steps = 0
                 with tr.span("stage", graph=handle.name):
                     self._probe("stage", handle.name)
                     parts = handle.partition(choice.nprocs)
@@ -840,12 +841,15 @@ class MicroBatchScheduler:
                     self.registry.touch_staged(handle.name)
                 self._probe("solve", handle.name)
                 ms = self._sweep_cap(handle.name)
+                sweep_fn = handle.multisource_sweep_fn()
+                # the default sweep's segmented min-scan depth, staged
+                # with the operands; a dynamic sweep_fn scatters instead
+                scan_steps = handle.cg.in_runs()[2] if sweep_fn is None else 0
                 t0 = time.perf_counter() if obs else 0.0
                 with tr.span("launch"):
                     D, sw, conv = sssp_multisource_csr(
                         ops, jnp.asarray(padded, jnp.int32),
-                        n=handle.n, sweep_fn=handle.multisource_sweep_fn(),
-                        max_sweeps=ms)
+                        n=handle.n, sweep_fn=sweep_fn, max_sweeps=ms)
                 with tr.span("wait"):
                     jax.block_until_ready((D, sw, conv))
                 rows, sw, conv = _fetch(tr, (D, sw, conv))
@@ -865,14 +869,16 @@ class MicroBatchScheduler:
                     sp.set(engine=engine, graph=handle.name, n=handle.n,
                            m=handle.m, B=bucket,
                            P=choice.nprocs if choice.sharded else 1,
-                           sweeps=int(sw), edges_relaxed=edges,
+                           sweeps=int(sw), scan_steps=scan_steps,
+                           edges_relaxed=edges,
                            occupancy=round(occupancy, 4),
                            converged=converged)
                 cl.emit(engine=engine, graph=handle.name, n=handle.n,
                         m=handle.m, batch=bucket,
                         nprocs=choice.nprocs if choice.sharded else 1,
-                        sweeps=int(sw), edges_relaxed=edges,
-                        wall_ms=wall_ms, converged=converged)
+                        sweeps=int(sw), scan_steps=scan_steps,
+                        edges_relaxed=edges, wall_ms=wall_ms,
+                        converged=converged)
         if not converged:
             raise NotConverged(
                 f"batched solve on {handle.name!r} ({len(distinct)} "

@@ -310,3 +310,24 @@ def test_api_shim_emits_schema_valid_cost_records():
     res2 = shortest_paths(cg, 0, engine="frontier")
     assert np.array_equal(res.dist, res2.dist)
     assert len(cl.records) == 1
+
+
+def test_api_shim_records_the_multisource_scan_depth():
+    """The batched engine's cost record and solve span carry the depth
+    of its segmented min-scan; engines without the scan carry 0."""
+    cg = C.random_csr_graph(64, 192, seed=8)
+    cl, tr = CostLog(), Tracer()
+    prev, prev_tr = set_cost_log(cl), set_tracer(tr)
+    try:
+        res = shortest_paths(cg, [0, 5, 9], engine="multisource_csr")
+        shortest_paths(cg, 0, engine="frontier")
+    finally:
+        set_cost_log(prev)
+        set_tracer(prev_tr)
+    depth = cg.in_runs()[2]
+    assert res.scan_steps == depth > 0
+    assert [(r.engine, r.scan_steps) for r in cl.records] == [
+        ("multisource_csr", depth), ("frontier", 0)]
+    assert [s.args["scan_steps"] for s in tr.spans
+            if s.name == "solve"] == [depth, 0]
+    assert validate_cost_records([r.to_dict() for r in cl.records]) == []

@@ -127,7 +127,10 @@ def test_multisource_csr_fits_v5e(one_chip):
     n, arcs, s = 2 ** 20, 6 * 2 ** 20, 16
     ops = {"src": _spec((arcs,), jnp.int32, one_chip),
            "dst": _spec((arcs,), jnp.int32, one_chip),
-           "w": _spec((arcs,), jnp.float32, one_chip)}
+           "w": _spec((arcs,), jnp.float32, one_chip),
+           "rank": _spec((arcs,), jnp.int32, one_chip),
+           "last": _spec((n,), jnp.int32, one_chip),
+           "scan_steps": _spec((), jnp.int32, one_chip)}
     ma = sssp_multisource_csr.lower(
         ops, _spec((s,), jnp.int32, one_chip), n=n).compile().memory_analysis()
     need = (ma.argument_size_in_bytes + ma.output_size_in_bytes

@@ -7,11 +7,13 @@ stages.
   allocates nothing;
 - a scheduler solve opens ``launch`` / ``wait`` / ``fetch`` children, each
   ``fetch`` with the bytes it read, and the traced path reads the engine's
-  scalars in one ``jax.device_get``;
+  scalars in one ``jax.device_get``; a batch solve reports its min-scan
+  depth (``scan_steps``);
 - the served engines' ``jax.named_scope`` stages reach the compiled HLO's
   ``op_name`` metadata, which the device trace reports as ``tf_op``.
 """
 import glob
+import math
 import os
 import re
 import tracemalloc
@@ -25,7 +27,7 @@ from jax._src.array import ArrayImpl
 from repro.core import csr as C
 from repro.core.bellman_csr import csr_operands, sssp_multisource_csr
 from repro.core.frontier import frontier_operands, sssp_frontier
-from repro.obs import NULL_TRACER, Tracer, set_tracer
+from repro.obs import NULL_TRACER, CostLog, Tracer, set_cost_log, set_tracer
 from repro.obs import trace as trace_mod
 from repro.serve import DistanceCache, GraphRegistry, MicroBatchScheduler
 
@@ -160,6 +162,23 @@ def test_batch_tick_opens_launch_wait_fetch(graph):
     # bucket 4: four f32 rows, the int32 sweep count and the flag
     assert kids[-1].args["bytes"] == 4 * graph.n * 4 + 4 + 1
     assert [s.name for s in _children(tr, "tick")] == ["batch_solve"]
+
+
+def test_batch_solve_reports_the_scan_depth(graph):
+    """The batched sweep's segmented min-scan takes ceil(log2(largest
+    in-degree)) doubling steps; the span and the cost record say so."""
+    sched = _scheduler(graph)
+    cl = CostLog()
+    prev = set_cost_log(cl)
+    try:
+        tr, _ = _traced_tick(sched, [(1, None), (2, None)])
+    finally:
+        set_cost_log(prev)
+    depth = math.ceil(math.log2(np.diff(graph.indptr).max()))
+    (solve,) = [s for s in tr.spans if s.name == "batch_solve"]
+    assert solve.args["scan_steps"] == depth > 0
+    (rec,) = cl.records
+    assert (rec.engine, rec.scan_steps) == ("multisource_csr", depth)
 
 
 def test_traced_p2p_reads_the_scalars_in_one_transfer(graph, monkeypatch):
