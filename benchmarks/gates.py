@@ -1,7 +1,6 @@
 """Shared bench-gate checking — one copy of the PASS/FAIL contract.
 
-Every tracked benchmark (run_bench, serve_bench, dynamic_bench,
-tune_bench) writes a JSON doc whose gate sections live under top-level
+Every tracked benchmark (run_bench, serve_bench, dynamic_bench) writes a JSON doc whose gate sections live under top-level
 keys named ``gate`` or ``gate_*``, each shaped
 ``{"rule": str, "pass": bool, ...}`` (absent or ``None`` when that leg
 didn't run).  The printing + enforcement of those sections used to be
@@ -13,7 +12,7 @@ copy-pasted per bench; it lives here now:
   gate (the benches call this right after writing their JSON);
 - a CLI for CI and operators::
 
-      python -m benchmarks.gates --check BENCH_sssp.json BENCH_tune.json
+      python -m benchmarks.gates --check BENCH_sssp.json BENCH_serve.json
 
   exits 1 if any named file has a failing gate (default: every
   ``BENCH_*.json`` in the current directory).

@@ -1,7 +1,6 @@
-"""Assemble EXPERIMENTS.md §Dry-run, §Roofline, §SSSP-bench, §Serve-bench,
-§Dynamic-bench, §Tune-bench, and §Weak-scaling tables from the dry-run
-JSON records, BENCH_sssp.json, BENCH_serve.json, BENCH_dynamic.json,
-BENCH_tune.json, and experiments/bench/weak_scaling.csv (single sources
+"""Assemble EXPERIMENTS.md §SSSP-bench, §Serve-bench, §Dynamic-bench and
+§Weak-scaling tables from BENCH_sssp.json, BENCH_serve.json,
+BENCH_dynamic.json and experiments/bench/weak_scaling.csv (single sources
 of truth), leaving hand-written sections (§Paper, §Perf) intact via
 marker comments.
 
@@ -10,75 +9,19 @@ marker comments.
 from __future__ import annotations
 
 import csv
-import glob
 import json
 import os
 
 from benchmarks.common import OUT_DIR, REPO
 
-DRYRUN_DIR = os.path.join(REPO, "experiments", "dryrun")
 BENCH_JSON = os.path.join(REPO, "BENCH_sssp.json")
 SERVE_JSON = os.path.join(REPO, "BENCH_serve.json")
 DYNAMIC_JSON = os.path.join(REPO, "BENCH_dynamic.json")
-TUNE_JSON = os.path.join(REPO, "BENCH_tune.json")
 WEAK_CSV = os.path.join(OUT_DIR, "weak_scaling.csv")
 MD = os.path.join(REPO, "EXPERIMENTS.md")
 
 BEGIN = "<!-- BEGIN GENERATED:{} -->"
 END = "<!-- END GENERATED:{} -->"
-
-
-def load(tagged: bool):
-    """baseline records have filenames <arch>__<shape>__{pod|multipod};
-    anything with a --tag suffix is a §Perf variant."""
-    recs = []
-    for f in sorted(glob.glob(os.path.join(DRYRUN_DIR, "*.json"))):
-        base = os.path.basename(f)[:-5]
-        parts = base.split("__")
-        is_tagged = len(parts) < 3 or parts[2] not in ("pod", "multipod")
-        with open(f) as fh:
-            r = json.load(fh)
-        r["_file"] = base
-        if is_tagged == tagged:
-            recs.append(r)
-    return recs
-
-
-def dryrun_table(recs) -> str:
-    rows = ["| arch | shape | mesh | chips | compile_s | params+temp GB/dev "
-            "| all-gather GB | all-reduce GB | a2a GB | cperm GB |",
-            "|---|---|---|---|---|---|---|---|---|---|"]
-    for r in recs:
-        m = r["memory_analysis"]
-        w = r["weighted"]["collective_bytes"]
-        gbdev = (m.get("argument_size_in_bytes", 0)
-                 + m.get("temp_size_in_bytes", 0)) / 1e9
-        rows.append(
-            f"| {r['arch']} | {r['shape']} | {r['mesh']} | {r['chips']} "
-            f"| {r['compile_s']:.1f} | {gbdev:.1f} "
-            f"| {w['all-gather']/1e9:.2f} | {w['all-reduce']/1e9:.2f} "
-            f"| {w['all-to-all']/1e9:.2f} "
-            f"| {w['collective-permute']/1e9:.2f} |")
-    return "\n".join(rows)
-
-
-def roofline_table(recs) -> str:
-    rows = ["| arch | shape | mesh | mxu_s | vpu_s | mem_s | coll_s "
-            "| lat_s | dominant | useful | mfu |",
-            "|---|---|---|---|---|---|---|---|---|---|---|"]
-    for r in recs:
-        rf = r["roofline"]
-        ur = rf.get("useful_ratio")
-        mfu = r.get("mfu_fraction")
-        rows.append(
-            f"| {r['arch']} | {r['shape']} | {r['mesh']} "
-            f"| {rf['compute_s']:.4f} | {rf['vpu_s']:.4f} "
-            f"| {rf['memory_s']:.4f} | {rf['collective_s']:.4f} "
-            f"| {rf.get('latency_s', 0):.4f} "
-            f"| {rf['dominant']} "
-            f"| {'' if ur is None else f'{ur:.2f}'} "
-            f"| {'' if mfu is None else f'{mfu:.4f}'} |")
-    return "\n".join(rows)
 
 
 def bench_tables(path: str) -> str:
@@ -276,38 +219,6 @@ def dynamic_table(path: str) -> str:
     return "\n".join(rows)
 
 
-def tune_table(path: str) -> str:
-    """BENCH_tune.json (benchmarks/tune_bench.py) -> per-leg race of the
-    measured-model policy against the hard-coded thresholds plus the
-    gate_tune summary."""
-    with open(path) as f:
-        doc = json.load(f)
-    meta = doc["meta"]
-    cov = meta.get("model_coverage", {})
-    rows = [f"jax {meta['jax']} on {meta['backend']}"
-            f"{' (smoke)' if meta.get('smoke') else ''}, best of "
-            f"{meta['repeats']}; model fitted from "
-            f"`{os.path.basename(meta['calibration'])}` "
-            f"({cov.get('records', '?')} calibrated points over "
-            f"{len(cov.get('engines', []))} engine groups); every leg "
-            "solves `engine=\"auto\"` under each policy and the answers "
-            "are bitwise-compared.",
-            "",
-            "| corpus | n | P | threshold engine | ms | model engine "
-            "| ms | via | ratio |",
-            "|---|---|---|---|---|---|---|---|---|"]
-    for r in doc["results"]:
-        rows.append(
-            f"| {r['corpus']} | {r['n']} | {r['nprocs']} "
-            f"| {r['base']['engine']} | {r['base']['wall_s'] * 1e3:.2f} "
-            f"| {r['tuned']['engine']} | {r['tuned']['wall_s'] * 1e3:.2f} "
-            f"| {r['tuned']['via']} | {r['ratio']} |")
-    gate = doc["gate_tune"]
-    rows += ["", f"**Gate** ({gate['rule']}): "
-                 f"{'PASS' if gate['pass'] else 'FAIL'}"]
-    return "\n".join(rows)
-
-
 def weak_scaling_table(path: str) -> str:
     """experiments/bench/weak_scaling.csv (benchmarks/weak_scaling.py) ->
     fixed-n/proc scaling table: dense column slabs vs the vertex-
@@ -335,28 +246,21 @@ def splice(text: str, name: str, content: str) -> str:
 
 
 def main():
-    recs = load(tagged=False)
     text = open(MD).read() if os.path.exists(MD) else "# EXPERIMENTS\n"
-    if recs:
-        text = splice(text, "dryrun", dryrun_table(recs))
-        text = splice(text, "roofline", roofline_table(recs))
     if os.path.exists(BENCH_JSON):
         text = splice(text, "sssp-bench", bench_tables(BENCH_JSON))
     if os.path.exists(SERVE_JSON):
         text = splice(text, "serve-bench", serve_table(SERVE_JSON))
     if os.path.exists(DYNAMIC_JSON):
         text = splice(text, "dynamic-bench", dynamic_table(DYNAMIC_JSON))
-    if os.path.exists(TUNE_JSON):
-        text = splice(text, "tune-bench", tune_table(TUNE_JSON))
     if os.path.exists(WEAK_CSV):
         text = splice(text, "weak-scaling", weak_scaling_table(WEAK_CSV))
     with open(MD, "w") as f:
         f.write(text)
-    print(f"wrote tables for {len(recs)} dry-run records"
-          f"{' + SSSP bench' if os.path.exists(BENCH_JSON) else ''}"
+    print("wrote tables"
+          f"{' SSSP bench' if os.path.exists(BENCH_JSON) else ''}"
           f"{' + serve bench' if os.path.exists(SERVE_JSON) else ''}"
           f"{' + dynamic bench' if os.path.exists(DYNAMIC_JSON) else ''}"
-          f"{' + tune bench' if os.path.exists(TUNE_JSON) else ''}"
           f"{' + weak scaling' if os.path.exists(WEAK_CSV) else ''}"
           f" into {MD}")
 

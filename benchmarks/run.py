@@ -1,10 +1,8 @@
-"""Benchmark orchestrator: one module per paper table/figure + roofline.
+"""Benchmark orchestrator: one module per paper table/figure.
 
     PYTHONPATH=src python -m benchmarks.run [--quick] [--only table3,...]
 
-Outputs CSVs under experiments/bench/ and prints a summary.  Roofline rows
-come from the dry-run JSONs (run ``python -m repro.launch.dryrun --all``
-to regenerate them).
+Outputs CSVs under experiments/bench/ and prints a summary.
 """
 from __future__ import annotations
 
@@ -12,8 +10,8 @@ import argparse
 import sys
 import time
 
-from benchmarks import (fig23_size_sweep, roofline, table3_density,
-                        table4_scaling, weak_scaling)
+from benchmarks import (fig23_size_sweep, table3_density, table4_scaling,
+                        weak_scaling)
 
 # table4 and weak run each process count in a child that needs the
 # device, so they go before anything in this process touches JAX (a parent
@@ -23,7 +21,6 @@ BENCHES = {
     "weak": weak_scaling.run,       # the experiment the paper couldn't run
     "table3": table3_density.run,
     "fig23": fig23_size_sweep.run,
-    "roofline": roofline.run,
 }
 
 

@@ -1,17 +1,12 @@
-"""Quickstart: the paper's three SSSP engines + a tiny LM through the
-public API, in under a minute on CPU.
+"""Quickstart: the paper's three SSSP engines and a multi-source batch
+through the public API, in under a minute on CPU.
 
     PYTHONPATH=src python examples/quickstart.py
 """
 import numpy as np
 
-import jax
-import jax.numpy as jnp
-
 from repro.core import graph as G
 from repro.core.api import shortest_paths
-from repro.configs import get_config, make_smoke
-from repro.models import transformer as T
 
 # --- 1. SSSP: serial (Alg.1), fixpoint (Alg.3/4), Pallas kernel ----------
 g = G.random_graph(500, 1500, seed=0)
@@ -28,17 +23,4 @@ for engine in ("serial", "bellman", "bellman_kernel"):
 res = shortest_paths(g, np.array([0, 7, 99]), engine="multisource")
 print(f"  multisource     : dist matrix {res.dist.shape}, {res.sweeps} sweeps")
 
-# --- 3. a model from the assigned-architecture zoo -------------------------
-cfg = make_smoke(get_config("gemma3-1b"))
-params = T.init_params(jax.random.PRNGKey(0), cfg)
-tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, cfg.vocab_size)
-loss, metrics = T.train_loss(params, {"tokens": tokens, "labels": tokens}, cfg)
-print(f"\n{cfg.name}: one train-loss eval = {float(loss):.3f}")
-
-logits, caches, pos = T.prefill(params, tokens, cfg, max_len=40)
-tok = jnp.argmax(logits, -1)[:, None]
-for _ in range(5):
-    logits, caches, pos = T.decode_step(params, tok, pos, caches, cfg)
-    tok = jnp.argmax(logits, -1)[:, None]
-print(f"decoded 5 tokens, cache pos now {np.asarray(pos)}")
 print("\nquickstart OK")

@@ -2,7 +2,7 @@
 Parallelization of Dijkstra's Algorithm Using MPI and CUDA".
 
 Subpackages: ``core`` (SSSP engines + graph containers), ``kernels``
-(Pallas relax kernels), ``serve`` (query-serving subsystem), ``launch``
-(drivers), plus the training-substrate packages (``configs``, ``models``,
-``sharding``, ``train``, ``data``, ``checkpoint``).
+(Pallas relax kernels), ``serve`` (query-serving subsystem), ``dynamic``
+(mutable-graph overlays + incremental repair), ``obs`` (spans, counters,
+cost records), ``launch`` (drivers).
 """

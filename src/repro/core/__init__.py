@@ -1,3 +1,3 @@
-# The paper's primary contribution — implement the SYSTEM here
-# (scheduler, optimizer, data path, serving loop, etc.) in the
-# host framework. Add sibling subpackages for substrates.
+# The paper's SSSP engines (serial, dense and CSR fixpoints, frontier,
+# Δ-stepping, the sharded families) and the graph containers they read;
+# core/api.py is the one facade over them.

@@ -1,6 +1,6 @@
 """Axis and mesh helpers: every sharded SSSP engine accepts a single
-mesh-axis name or a tuple of names (e.g. ("pod", "data", "model") to shard
-columns over all 512 chips in the multi-pod dry-run)."""
+mesh-axis name or a tuple of names (e.g. ("pod", "data") to shard
+columns over every device of a two-level mesh)."""
 from __future__ import annotations
 
 import math

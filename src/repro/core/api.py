@@ -217,27 +217,19 @@ def _edge_count(g) -> int:
     return 0
 
 
-def _resolve_auto(g, source, *, engine, mesh, axis, delta, target):
+def _resolve_auto(g, source, *, target):
     """Resolve ``engine="auto"`` through the serving layer's one dispatch
     seam (serve/dispatch.py): the process-default policy picks the engine
-    AND its statics — a measured-model policy (repro/tune/select.py)
-    returns a calibrated Δ, which binds only when the caller passed none
-    (an explicit ``delta=`` always wins).  Lazy import keeps core free of
-    a hard serve dependency.  Returns the concrete
-    ``(engine, mesh, axis, delta)``; non-auto calls pass through."""
-    if engine != "auto":
-        return engine, mesh, axis, delta
+    and its mesh; the caller's own ``delta=`` passes through unchanged.
+    Lazy import keeps core free of a hard serve dependency.  Returns the
+    concrete ``(engine, mesh, axis)``."""
     from repro.serve.dispatch import default_policy
 
     multi = np.ndim(source) > 0
     choice = default_policy().choose(
         g, kind="batch" if multi else ("p2p" if target is not None
                                        else "single"))
-    engine, mesh, axis = choice.engine, choice.mesh, choice.axis
-    if (delta is None and choice.delta is not None
-            and engine in _DELTA_CONSUMERS):
-        delta = float(choice.delta)
-    return engine, mesh, axis, delta
+    return choice.engine, choice.mesh, choice.axis
 
 
 def shortest_paths(
@@ -273,11 +265,10 @@ def shortest_paths(
     import time as _time
 
     # resolve "auto" HERE so the record carries the routed engine's real
-    # decision inputs (mesh arity, model-chosen Δ) — the facade below
-    # passes the already-concrete engine straight through.
-    engine, mesh, axis, delta = _resolve_auto(
-        g, source, engine=engine, mesh=mesh, axis=axis, delta=delta,
-        target=target)
+    # mesh arity — the facade below passes the already-concrete engine
+    # straight through.
+    if engine == "auto":
+        engine, mesh, axis = _resolve_auto(g, source, target=target)
     kw = dict(engine=engine, mesh=mesh, axis=axis, block=block,
               max_sweeps=max_sweeps, delta=delta, target=target,
               target_lb=target_lb)
@@ -367,11 +358,8 @@ def _shortest_paths(
     """
     if engine == "auto":
         # the serving layer's one dispatch seam (serve/dispatch.py) picks
-        # the engine and its statics (a model policy's calibrated Δ binds
-        # only when the caller passed no delta= of their own).
-        engine, mesh, axis, delta = _resolve_auto(
-            g, source, engine=engine, mesh=mesh, axis=axis, delta=delta,
-            target=target)
+        # the engine and its mesh.
+        engine, mesh, axis = _resolve_auto(g, source, target=target)
     if engine not in ENGINES:
         raise ValueError(f"unknown engine {engine!r}; choose from {ENGINES}")
     # Δ validation is EAGER (before any staging): a bad width would

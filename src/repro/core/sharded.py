@@ -157,12 +157,3 @@ def dijkstra_sharded(
         return loc_dist, loc_pred
 
     return run(adj_padded, jnp.asarray(source, jnp.int32))
-
-
-def dijkstra_sharded_jit(mesh, axis="data", n_true=None, minloc="allgather"):
-    """jit-compiled closure (lower/compile entry point for the dry-run)."""
-    def fn(adj_padded, source):
-        return dijkstra_sharded(
-            adj_padded, source, mesh, axis=axis, n_true=n_true, minloc=minloc
-        )
-    return jax.jit(fn)

@@ -1,5 +1,6 @@
-"""Launch drivers: SSSP runs (sssp_run, sssp_serve), serving (serve),
-training (train), dry-run/roofline analysis (dryrun, hlo_analysis,
-memory_model).  Modules are imported on demand — several force XLA flags
-at import time, so nothing is re-exported here.
+"""Launch drivers: one-shot SSSP runs (sssp_run), query serving
+(sssp_serve), dynamic-graph churn replay (sssp_dynamic), and the process
+set-up they share (runtime).  Modules are imported on demand — the
+drivers set device counts before JAX starts, so nothing is re-exported
+here.
 """

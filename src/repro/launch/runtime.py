@@ -1,6 +1,5 @@
 """Process set-up shared by the entry points (``chip_smoke.py``, the
-``repro.launch.sssp_*`` drivers, the calibration sweep and the benchmark
-modules).  Nothing here runs on import: each entry point calls these
+``repro.launch.sssp_*`` drivers and the benchmark modules).  Nothing here runs on import: each entry point calls these
 helpers itself, first thing in its ``main``, before any JAX operation.
 """
 from __future__ import annotations

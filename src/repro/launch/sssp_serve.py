@@ -3,9 +3,9 @@ subsystem and report latency/throughput/cache metrics.
 
     PYTHONPATH=src python -m repro.launch.sssp_serve --smoke
 
-Mirrors launch/serve.py's shape (queue -> batcher -> engine, per-request
-latency + aggregate throughput), but for shortest-path queries: per
-scenario (uniform / zipf / p2p, see repro/serve/workload.py) the driver
+A queue -> batcher -> engine loop with per-request latency and
+aggregate throughput, for shortest-path queries: per scenario
+(uniform / zipf / p2p, see repro/serve/workload.py) the driver
 registers the graphs (with ALT landmarks), generates an open-loop arrival
 trace, and replays it in wall-clock time — events are submitted when
 their arrival time passes, the scheduler ticks whenever work is queued,
@@ -338,12 +338,6 @@ def main(argv=None):
                          "PATH, per-solve cost records to "
                          "PATH-with-.cost.jsonl; both are schema-"
                          "validated at exit (repro/obs)")
-    ap.add_argument("--calibration", default=None, metavar="PATH",
-                    help="serve through the measured cost model fitted "
-                         "from this CALIBRATION.json (repro/tune) "
-                         "instead of the hard-coded thresholds; "
-                         "out-of-support queries still fall back to "
-                         "them")
     args = ap.parse_args(argv)
     enable_compile_cache()
     use_devices(args.devices)
@@ -358,17 +352,8 @@ def main(argv=None):
     rate = args.rate or (2000.0 if args.smoke else 500.0)
     verify = args.verify if args.verify is not None else args.smoke
     scenarios = SCENARIOS if args.scenario == "all" else (args.scenario,)
-    if args.calibration:
-        from repro.tune.model import load_model
-        from repro.tune.select import TunedPolicy
-        dispatch = TunedPolicy(load_model(args.calibration),
-                               shard_threshold=args.shard_threshold,
-                               nprocs=args.devices)
-        print(f"[sssp_serve] tuned dispatch from {args.calibration}: "
-              f"{dispatch.model.coverage()['engines']}", flush=True)
-    else:
-        dispatch = DispatchPolicy(shard_threshold=args.shard_threshold,
-                                  nprocs=args.devices)
+    dispatch = DispatchPolicy(shard_threshold=args.shard_threshold,
+                              nprocs=args.devices)
     set_default_policy(dispatch)    # engine="auto" callers agree with us
     if dispatch.nprocs > 1:
         print(f"[sssp_serve] sharded route: {dispatch.nprocs} devices, "

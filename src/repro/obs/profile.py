@@ -118,8 +118,8 @@ class CostLog:
         scan_steps: int = 0,
     ) -> None:
         if not backend or not device_kind:
-            # v2: stamp the measuring hardware so fitted models can
-            # refuse records from a different backend (tune/replay.py).
+            # v2: stamp the measuring hardware, so a record says which
+            # backend and device kind its wall_ms came from.
             be, dk = backend_info()
             backend = backend or be
             device_kind = device_kind or dk

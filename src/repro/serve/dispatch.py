@@ -1,12 +1,11 @@
-"""Engine-selection seam: one place that decides single-device vs
-vertex-partitioned sharded solves.
+"""Engine-selection seam: the one policy that decides which engine runs
+each solve, single-device or vertex-partitioned sharded.
 
 Both entry points into the engine stack route through here instead of
 hard-coding engine names: ``core.api.shortest_paths(engine="auto")`` for
 one-shot callers, and ``MicroBatchScheduler`` for every served batch /
 point-to-point solve (serve/scheduler.py takes a ``dispatch=`` policy).
-Centralizing the choice keeps the two paths answering identically and
-gives operators a single knob set.
+Centralizing the choice keeps the two paths answering identically.
 
 The policy mirrors the paper's own crossover: the MPI arm wins only once
 the per-rank block is big enough to hide the exchange (its Table III
@@ -17,10 +16,12 @@ multiple devices to partition across.  Below the shard crossover, large
 single-source solves on static CSR graphs route to the Δ-stepping
 engine (core/delta_stepping.py) when the graph's weight profile keeps
 its light in-ELL narrow — ``delta_threshold`` / ``would_delta`` gate
-this, and the answers stay bitwise-identical either way.  Dynamic graphs (PR 5 overlays)
+this, and the answers stay bitwise-identical either way.  Dynamic graphs
 never shard: their serving path relies on overlay-native operands and
 incremental repair, both of which are built on the single-device staged
 views (a frozen CsrPartition would go stale at the first mutation).
+Each engine runs with its own default statics (Δ width, scatter chunk);
+the policy picks only the engine and its mesh.
 
 The mesh is built once per (nprocs, axis) and cached module-wide —
 serving solves hundreds of queries per second and mesh construction is
@@ -70,27 +71,11 @@ def serving_mesh(nprocs: int, axis: str = "data") -> jax.sharding.Mesh:
 @dataclasses.dataclass(frozen=True)
 class EngineChoice:
     """One routing decision: which engine, on which mesh (None for the
-    single-device engines), and the shard arity cache keys must carry.
-
-    The optional statics fields let a policy return not just the engine
-    but its tuning parameters, so every caller's magic numbers route
-    through this one seam (ROADMAP item 4): ``delta`` is the Δ-bucket
-    width for the engines that consume one, ``chunk`` the frontier
-    engines' scatter chunk, ``batch_cap`` the padded multisource bucket
-    ceiling the scheduler should admit per tick.  ``None`` (the
-    threshold policy's value) means "caller keeps its default" — the
-    measured-model policy (repro/tune/select.py) fills them from
-    calibrated data.  ``via`` names which arm decided: ``"threshold"``
-    for the hard-coded size rules, ``"model"`` for a fitted cost model.
-    """
+    single-device engines), and the shard arity cache keys must carry."""
     engine: str
     mesh: Optional[jax.sharding.Mesh]
     axis: str = "data"
     nprocs: int = 1
-    delta: Optional[float] = None
-    chunk: Optional[int] = None
-    batch_cap: Optional[int] = None
-    via: str = "threshold"
 
     @property
     def sharded(self) -> bool:
@@ -165,14 +150,6 @@ class DispatchPolicy:
 
         return bool(delta_profile(g)["routable"])
 
-    def batch_cap(self, g) -> Optional[int]:
-        """Per-tick distinct-source admission ceiling for batched solves
-        of ``g``, or ``None`` for "scheduler keeps its ``max_batch``".
-        Pure (no mesh/staging), called at admission time — the threshold
-        policy has no opinion; the measured-model policy returns the
-        calibrated bucket size (tune/select.py)."""
-        return None
-
     def choose(self, g, *, kind: str = "single") -> EngineChoice:
         """Route one solve.  ``g`` is anything with an ``n`` (CsrGraph,
         Graph, DynamicGraph, GraphHandle-like) or a dense square array;
@@ -226,7 +203,7 @@ def set_default_policy(
 def policy_override(policy: Optional[DispatchPolicy]):
     """Scoped :func:`set_default_policy`: installs ``policy`` for the
     ``with`` body and restores the previous one on exit (exception
-    included) — how tests and the tuner race two policies without
+    included) — how tests run a solve under another policy without
     leaking global state.  Yields the installed policy."""
     prev = set_default_policy(policy)
     try:

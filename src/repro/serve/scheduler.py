@@ -640,30 +640,14 @@ class MicroBatchScheduler:
 
     # -- engine paths -----------------------------------------------------
 
-    def _bucket(self, count: int, cap: Optional[int] = None) -> int:
+    def _bucket(self, count: int) -> int:
         """Smallest power of two >= count, clamped to max_batch — the
         memoized source-axis sizes that keep repeat ticks on the same
-        compiled multisource solve.  ``cap`` (a policy's calibrated
-        ``EngineChoice.batch_cap``) tightens the clamp further, but never
-        below ``count`` — every admitted distinct source must fit."""
+        compiled multisource solve."""
         b = 1
         while b < count:
             b *= 2
-        b = min(b, self.max_batch)
-        if cap is not None:
-            b = max(count, min(b, int(cap)))
-        return b
-
-    def _admission_limit(self, handle) -> int:
-        """Distinct sources admitted per tick for ``handle``: the
-        scheduler's ``max_batch`` tightened by the dispatch policy's
-        calibrated per-graph bucket ceiling (``DispatchPolicy.batch_cap``
-        — None from the threshold policy, the measured-best B from a
-        tuned one)."""
-        cap = self.dispatch.batch_cap(handle)
-        if cap is None:
-            return self.max_batch
-        return max(1, min(self.max_batch, int(cap)))
+        return min(b, self.max_batch)
 
     def _probe(self, site: str, name: str) -> None:
         """Fault-plan probe at a raising seam (solve / stage)."""
@@ -750,20 +734,12 @@ class MicroBatchScheduler:
                 lb = None if not np.isfinite(lb) else jnp.float32(lb)
             self._probe("solve", handle.name)
             ms = self._sweep_cap(handle.name)
-            # model-chosen frontier statics ride the choice: Δ throttles
-            # the bucket schedule, chunk the scatter width — both change
-            # only the schedule, never the fixpoint bytes.
-            skw = {}
-            if choice.delta is not None:
-                skw["delta"] = float(choice.delta)
-            if choice.chunk is not None:
-                skw["chunk"] = int(choice.chunk)
             t0 = time.perf_counter() if obs else 0.0
             with tr.span("launch"):
                 d, _, sw, e, conv = sssp_frontier(
                     ops, jnp.int32(q.source), n=handle.n,
                     sweep_fn=handle.frontier_sweep_fn(), max_sweeps=ms,
-                    target=jnp.int32(q.target), target_lb=lb, **skw,
+                    target=jnp.int32(q.target), target_lb=lb,
                 )
             with tr.span("wait"):
                 jax.block_until_ready((d, sw, e, conv))
@@ -799,7 +775,7 @@ class MicroBatchScheduler:
                 seen.add(q.source)
                 distinct.append(q.source)
         choice = self.dispatch.choose(handle, kind="batch")
-        bucket = self._bucket(len(distinct), choice.batch_cap)
+        bucket = self._bucket(len(distinct))
         padded = distinct + [distinct[0]] * (bucket - len(distinct))
         tr = get_tracer()
         cl = get_cost_log()
@@ -1025,11 +1001,10 @@ class MicroBatchScheduler:
             allowed: list[int] = []
             allowed_set: set[int] = set()
             take, defer = [], []
-            limit = self._admission_limit(handle)
             for q in need_engine:
                 if q.source in allowed_set:
                     take.append(q)
-                elif len(allowed) < limit:
+                elif len(allowed) < self.max_batch:
                     allowed.append(q.source)
                     allowed_set.add(q.source)
                     take.append(q)

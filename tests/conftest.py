@@ -1,8 +1,7 @@
 # NOTE: XLA_FLAGS / device-count forcing is deliberately NOT set here —
 # smoke tests run on the single real CPU device.  Multi-device behavior is
 # covered by subprocess tests (test_integration.py) that set
-# --xla_force_host_platform_device_count in the child environment, and by
-# the dry-run (launch/dryrun.py) which owns its own flag.
+# --xla_force_host_platform_device_count in the child environment.
 import heapq
 
 import numpy as np

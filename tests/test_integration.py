@@ -1,28 +1,23 @@
-"""Subprocess integration tests: multi-device SSSP, failure-injection
-restart determinism, serving driver, DDP compression trainer.
+"""Subprocess integration tests: the multi-device SSSP engines and the
+``sssp_run`` driver across process counts.
 
 These spawn fresh Python processes so each can force its own XLA host
 device count (the in-process suite stays on the single real device)."""
-import json
 import os
-import re
-import shutil
 import subprocess
 import sys
 
-import numpy as np
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SRC = os.path.join(REPO, "src")
 
 
-def _run(code=None, module=None, args=(), devices=1, env=None, timeout=600):
+def _run(code=None, module=None, args=(), devices=1, timeout=600):
     e = dict(os.environ)
     e["PYTHONPATH"] = SRC + os.pathsep + e.get("PYTHONPATH", "")
     if devices > 1:
         e["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
-    e.update(env or {})
     cmd = [sys.executable]
     if code is not None:
         cmd += ["-c", code]
@@ -81,73 +76,6 @@ print("MINLOC_OK")
 
 
 @pytest.mark.slow
-def test_failure_injection_restart_is_bit_identical(tmp_path):
-    """Train 20 steps clean; train with a crash at step 12 + restart; the
-    post-restart losses must match the uninterrupted run exactly."""
-    ck1, ck2 = str(tmp_path / "a"), str(tmp_path / "b")
-    env = {"REPRO_EMIT_LOSSES": "1"}
-    base = ["--arch", "qwen1.5-0.5b", "--smoke", "--steps", "20",
-            "--batch", "4", "--seq", "32", "--ckpt-every", "5",
-            "--log-every", "100"]
-    r0 = _run(module="repro.launch.train", args=base + ["--ckpt-dir", ck1],
-              env=env)
-    assert r0.returncode == 0, r0.stderr
-    clean = json.loads(re.search(r"LOSSES (\[.*\])", r0.stdout).group(1))
-
-    r1 = _run(module="repro.launch.train",
-              args=base + ["--ckpt-dir", ck2, "--simulate-failure-at", "12"],
-              env=env)
-    assert r1.returncode != 0 and "simulated node failure" in r1.stderr
-
-    r2 = _run(module="repro.launch.train", args=base + ["--ckpt-dir", ck2],
-              env=env)
-    assert r2.returncode == 0, r2.stderr
-    assert "restored step 10" in r2.stdout
-    resumed = json.loads(re.search(r"LOSSES (\[.*\])", r2.stdout).group(1))
-    # steps 10..19 of the clean run == the resumed run
-    np.testing.assert_allclose(clean[10:], resumed, rtol=1e-6)
-
-
-@pytest.mark.slow
-def test_ddp_compressed_trainer_multidevice():
-    code = """
-import jax, jax.numpy as jnp
-from repro.configs import get_config, make_smoke
-from repro.train.state import init_train_state
-from repro.train.step import make_ddp_train_step
-from repro.train.optimizer import OptConfig, init_opt_state
-from repro.train import compression as comp
-cfg = make_smoke(get_config("qwen1.5-0.5b"))
-opt = OptConfig(lr=1e-3, warmup_steps=1, total_steps=30)
-from repro.core._axes import make_mesh
-mesh = make_mesh((4,), ("data",))
-key = jax.random.PRNGKey(0)
-st = init_train_state(key, cfg, opt)
-batch = {"tokens": jax.random.randint(key, (8, 32), 0, cfg.vocab_size),
-         "labels": jax.random.randint(key, (8, 32), 0, cfg.vocab_size)}
-ddp = jax.jit(make_ddp_train_step(cfg, opt, mesh, compress=True))
-p, o, e = st.params, init_opt_state(st.params, opt), comp.init_error_state(st.params)
-losses = []
-for _ in range(6):
-    p, o, e, loss = ddp(p, o, e, batch)
-    losses.append(float(loss))
-assert losses[-1] < losses[0], losses
-print("DDP_OK", losses[0], losses[-1])
-"""
-    r = _run(code=code, devices=4)
-    assert "DDP_OK" in r.stdout, r.stdout + r.stderr
-
-
-@pytest.mark.slow
-def test_serve_driver_runs():
-    r = _run(module="repro.launch.serve",
-             args=["--arch", "mamba2-130m", "--smoke", "--requests", "4",
-                   "--batch", "2", "--prompt-len", "16", "--gen", "4"])
-    assert r.returncode == 0, r.stderr
-    assert "tok/s" in r.stdout
-
-
-@pytest.mark.slow
 def test_sssp_run_driver_scaling_procs():
     r = _run(module="repro.launch.sssp_run",
              args=["--engine", "dijkstra_sharded", "--procs", "4",
@@ -155,52 +83,3 @@ def test_sssp_run_driver_scaling_procs():
                    "--repeats", "1"])
     assert r.returncode == 0, r.stderr
     assert "verify: OK" in r.stdout
-
-
-@pytest.mark.slow
-def test_elastic_restore_across_meshes(tmp_path):
-    """Checkpoint on 1 device, restore on an 8-device mesh (reshard-on-load)."""
-    ck = str(tmp_path / "ck")
-    r1 = _run(module="repro.launch.train",
-              args=["--arch", "mamba2-130m", "--smoke", "--steps", "6",
-                    "--batch", "4", "--seq", "32", "--ckpt-dir", ck,
-                    "--ckpt-every", "3"])
-    assert r1.returncode == 0, r1.stderr
-    r2 = _run(module="repro.launch.train",
-              args=["--arch", "mamba2-130m", "--smoke", "--steps", "8",
-                    "--batch", "4", "--seq", "32", "--ckpt-dir", ck,
-                    "--ckpt-every", "4", "--data-axis", "8"],
-              devices=8)
-    assert r2.returncode == 0, r2.stderr
-    assert "restored step 6" in r2.stdout
-
-
-@pytest.mark.slow
-def test_moe_ep_shard_map_matches_gspmd():
-    """The explicit expert-parallel shard_map MoE must produce the same
-    outputs as the GSPMD grouped path (same routing, same capacity
-    semantics) on a real (data=2, model=2) mesh."""
-    code = """
-import dataclasses, jax, numpy as np, jax.numpy as jnp
-from repro.configs import get_config, make_smoke
-from repro.models.moe import init_moe, moe
-cfg = dataclasses.replace(make_smoke(get_config("qwen2-moe-a2.7b")),
-                          expert_pad_to=8)
-from jax import set_mesh
-from repro.core._axes import make_mesh
-mesh = make_mesh((2, 2), ("data", "model"))
-p = init_moe(jax.random.PRNGKey(0), cfg)
-x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model))
-with set_mesh(mesh):
-    cfg_g = dataclasses.replace(cfg, moe_impl="gspmd")
-    cfg_e = dataclasses.replace(cfg, moe_impl="ep")
-    out_g, aux_g = jax.jit(lambda p, x: moe(p, x, cfg_g))(p, x)
-    out_e, aux_e = jax.jit(lambda p, x: moe(p, x, cfg_e))(p, x)
-err = np.abs(np.asarray(out_g, np.float32) - np.asarray(out_e, np.float32)).max()
-aerr = abs(float(aux_g) - float(aux_e))
-assert err < 2e-3, err
-assert aerr < 1e-4, (float(aux_g), float(aux_e))
-print("EP_OK", err, aerr)
-"""
-    r = _run(code=code, devices=4)
-    assert "EP_OK" in r.stdout, r.stdout + r.stderr
